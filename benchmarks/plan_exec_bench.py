@@ -116,6 +116,8 @@ def run(verbose=True, smoke=False) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     import json
     res = run()
     with open("BENCH_plan_exec.json", "w") as f:

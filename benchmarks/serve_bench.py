@@ -226,6 +226,8 @@ def run(verbose: bool = True, smoke: bool = False) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     import argparse
     import json
 

@@ -101,4 +101,6 @@ def run(verbose=True) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run()

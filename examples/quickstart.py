@@ -11,12 +11,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import apps, bitstream as bs, circuits
 from repro.core.appnet import APP_NETLISTS
 from repro.core.arch import StochIMCConfig, evaluate_stoch_imc
 from repro.core.executor import execute_value
 from repro.core.scheduler import schedule
 
+enable_compile_cache()
 key = jax.random.key(0)
 BL = 1024
 

@@ -6,57 +6,45 @@ examples/serve_lm.py for the SC stack):
     PYTHONPATH=src python examples/serve_sc.py
 
 Bursts of local-image-thresholding windows (LIT, Eq. 5-6) and kernel-density
-estimates (KDE, Eq. 10) arrive with shifting composition; the server buckets
-each burst into a canonical padded bank template, so after the first
-occurrence of each mix every burst reuses a warm BankPlan + jit program.
-Every result is bit-identical to a standalone ``appnet_stochastic`` call
-with the same per-request key.
+estimates (KDE, Eq. 10) arrive with shifting composition
+(``repro.serve.apps.LIT_KDE_BURSTS``); the server buckets each burst into a
+canonical padded bank template, so after the first occurrence of each mix
+every burst reuses a warm BankPlan + jit program.  Every result is
+bit-identical to a standalone ``appnet_stochastic`` call with the same
+per-request key.
 """
 import time
 
 import jax
 import numpy as np
 
-from repro.core.apps import KDE_N, kde_exact, lit_exact
-from repro.serve import BankServer, app_request
+from repro.compile_cache import enable_compile_cache
+from repro.core.apps import KDE_N
+from repro.serve import BankServer
+from repro.serve.apps import lit_kde_bursts
 
 BL = 256
-# Bursty traffic: (n_lit, n_kde) per burst — composition shifts burst to
-# burst but revisits earlier mixes, which is what the bucketing rewards.
-BURSTS = [(3, 1), (1, 3), (3, 1), (2, 2), (1, 3), (3, 1), (2, 2), (1, 3)]
 
 
 def main():
-    rng = np.random.default_rng(0)
+    enable_compile_cache()
     server = BankServer(max_slots=8, window_s=None)
-    key = jax.random.key(42)
-    req_id = 0
+    bursts = lit_kde_bursts(np.random.default_rng(0), jax.random.key(42), BL)
 
-    print(f"serving {sum(a + b for a, b in BURSTS)} requests "
-          f"in {len(BURSTS)} bursts (LIT 9x9 windows + KDE {KDE_N}-frame "
+    print(f"serving {sum(map(len, bursts))} requests "
+          f"in {len(bursts)} bursts (LIT 9x9 windows + KDE {KDE_N}-frame "
           f"histories, BL={BL})")
-    for bi, (n_lit, n_kde) in enumerate(BURSTS):
-        reqs, refs = [], []
-        for _ in range(n_lit):
-            a = rng.uniform(0.1, 0.9, size=(81,))
-            key, sub = jax.random.split(key)
-            reqs.append(app_request("lit", sub, BL, a=a))
-            refs.append(("LIT", float(lit_exact(a))))
-        for _ in range(n_kde):
-            x_t = rng.uniform(0.2, 0.8)
-            hist = rng.uniform(0.2, 0.8, size=(KDE_N,))
-            key, sub = jax.random.split(key)
-            reqs.append(app_request("kde", sub, BL, x_t=x_t, hist=hist))
-            refs.append(("KDE", float(kde_exact(x_t, hist))))
-
+    for bi, burst in enumerate(bursts):
         t0 = time.perf_counter()
-        results = server.serve(reqs)
+        results = server.serve([req for _, req, _ in burst])
         dt = (time.perf_counter() - t0) * 1e3
         line = []
-        for (what, exact), out in zip(refs, results):
+        for (app, _, exact), out in zip(burst, results):
             got = float(np.mean([np.asarray(v) for v in out.values()]))
-            line.append(f"{what} {got:.3f} (exact {exact:.3f})")
-        print(f"burst {bi}: {n_lit} LIT + {n_kde} KDE in {dt:7.1f} ms   "
+            line.append(f"{app.upper()} {got:.3f} (exact {exact:.3f})")
+        n_lit = sum(app == "lit" for app, _, _ in burst)
+        print(f"burst {bi}: {n_lit} LIT + {len(burst) - n_lit} KDE in "
+              f"{dt:7.1f} ms   "
               + "; ".join(line[:3]) + (" ..." if len(line) > 3 else ""))
 
     s = server.stats()

@@ -23,6 +23,7 @@ import time
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.apps import KDE_N
 from repro.serve import BankServer, app_request
 
@@ -34,6 +35,7 @@ OUT = "trace_serve.json"
 
 
 def main():
+    enable_compile_cache()
     rng = np.random.default_rng(0)
     server = BankServer(max_slots=8, window_s=None, trace=True)
     key = jax.random.key(42)

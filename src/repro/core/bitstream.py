@@ -119,13 +119,15 @@ def stream_row_seeds(key: jax.Array, lanes) -> jax.Array:
 def generate_batch_seeded(row_seeds: jax.Array, ps: jax.Array,
                           bitstream_length: int,
                           use_pallas: bool = False,
-                          word_window: tuple | None = None) -> jax.Array:
+                          word_window: tuple | None = None,
+                          interpret: bool | None = None) -> jax.Array:
     """Batched SNG from pre-mixed row seeds: ps (N, *batch) -> (N, *batch, W).
 
     Thresholds and packs by compare-and-accumulate over the 32 lane shifts —
     the (..., W, 32) unpacked uniform tensor of ``generate`` is never
     materialized.  ``use_pallas`` routes through the fused Pallas SNG kernel
-    (kernels/sng.py), bit-identical to the jnp fallback.
+    (kernels/sng.py), bit-identical to the jnp fallback; ``interpret``
+    forwards to it (None: compiled on a TPU).
 
     ``word_window=(start, n)`` generates only words ``[start, start + n)`` of
     the ``bitstream_length``-long streams — bit-identical to slicing a
@@ -139,7 +141,8 @@ def generate_batch_seeded(row_seeds: jax.Array, ps: jax.Array,
     ps = jnp.asarray(ps)
     thr = _threshold_u32(ps).reshape(ps.shape[0], -1)      # (N, B)
     if word_window is None:
-        words = sng_words(row_seeds, thr, w, use_pallas=use_pallas)
+        words = sng_words(row_seeds, thr, w, use_pallas=use_pallas,
+                          interpret=interpret)
         return words.reshape(ps.shape + (w,))
     start, n_win = word_window
     words = sng_words(row_seeds, thr, n_win, use_pallas=use_pallas,
@@ -149,20 +152,22 @@ def generate_batch_seeded(row_seeds: jax.Array, ps: jax.Array,
 
 def generate_batch(key: jax.Array, ps: jax.Array, bitstream_length: int,
                    lanes=None, use_pallas: bool = False,
-                   word_window: tuple | None = None) -> jax.Array:
+                   word_window: tuple | None = None,
+                   interpret: bool | None = None) -> jax.Array:
     """Generate N packed streams in one pass: ps (N, *batch) -> (N, *batch, W).
 
     ``lanes`` (default ``arange(N)``) assigns each row its key-lane index:
     rows with distinct lanes are independent; rows sharing a lane share their
     underlying uniforms (a correlation group — XOR of two such rows decodes
-    exact |a - b|).  ``word_window`` as in ``generate_batch_seeded``.
+    exact |a - b|).  ``word_window`` and ``interpret`` as in
+    ``generate_batch_seeded``.
     """
     ps = jnp.asarray(ps)
     if lanes is None:
         lanes = jnp.arange(ps.shape[0], dtype=jnp.uint32)
     return generate_batch_seeded(stream_row_seeds(key, lanes), ps,
                                  bitstream_length, use_pallas=use_pallas,
-                                 word_window=word_window)
+                                 word_window=word_window, interpret=interpret)
 
 
 def pack_bits(bits: jax.Array) -> jax.Array:

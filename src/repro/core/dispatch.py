@@ -20,6 +20,7 @@ Fault keying mirrors the reference interpreter exactly (whatever the
 """
 from __future__ import annotations
 
+import contextlib
 import warnings
 from functools import partial
 
@@ -80,8 +81,10 @@ def _execute_compiled(plan: ExecutionPlan, values: dict[str, jax.Array],
     generates once and slices, so only intermediate streams are bounded.
     Exact either way: chunks of an i.i.d. bitstream are independent, every
     op is word-local, and reassembly is a pure transpose.
-    ``megakernel``/``interpret`` select the whole-plan Pallas kernel for the
-    logic passes (``kernels/plan_megakernel``).
+    ``megakernel`` selects the whole-plan Pallas kernel for the logic passes
+    (``kernels/plan_megakernel``); ``interpret`` reaches every Pallas kernel
+    of the program (None: compiled on a TPU, see
+    ``kernels.common.resolve_interpret``).
     """
     from ..kernels import netlist_exec
 
@@ -109,7 +112,8 @@ def _execute_compiled(plan: ExecutionPlan, values: dict[str, jax.Array],
 
     streams = _gen_pi_streams(plan.pis, values, key, bitstream_length,
                               key_mode=key_mode, batch_shape=batch_shape,
-                              use_pallas=use_pallas, table=plan.stream_table)
+                              use_pallas=use_pallas, table=plan.stream_table,
+                              interpret=interpret)
 
     gate_fkeys = None
     if inject:
@@ -168,7 +172,8 @@ def _execute_chunked(plan: ExecutionPlan, values, key, bitstream_length: int,
     if key_mode != "batched":
         full = _gen_pi_streams(plan.pis, values, key, bitstream_length,
                                key_mode=key_mode, batch_shape=batch_shape,
-                               use_pallas=use_pallas, table=plan.stream_table)
+                               use_pallas=use_pallas, table=plan.stream_table,
+                               interpret=interpret)
 
     def body(carry, ci):
         if full is None:
@@ -176,7 +181,8 @@ def _execute_chunked(plan: ExecutionPlan, values, key, bitstream_length: int,
                 plan.pis, values, key, bitstream_length, key_mode=key_mode,
                 batch_shape=batch_shape, use_pallas=use_pallas,
                 table=plan.stream_table,
-                word_window=(ci * jnp.uint32(word_chunk), word_chunk))
+                word_window=(ci * jnp.uint32(word_chunk), word_chunk),
+                interpret=interpret)
         else:
             streams = {nm: jax.lax.dynamic_slice_in_dim(
                            v, ci * jnp.uint32(word_chunk), word_chunk, axis=-1)
@@ -445,7 +451,8 @@ def _execute_bank_impl(bank: BankPlan, values_seq, keys, flip_keys,
     native_batch: dict[int, tuple[int, ...]] = {}
     member_streams = _gen_bank_streams(bank, values_seq, keys,
                                        bitstream_length, key_mode, use_pallas,
-                                       batch_shapes, active=active)
+                                       batch_shapes, active=active,
+                                       interpret=interpret)
     inject = _faults.injecting(bitflip_rate, fault_model)
     for i, plan in enumerate(bank.members):
         pre = member_prefix(i)
@@ -653,7 +660,8 @@ def _dispatch_many(nets, values_seq, keys, bitstream_length: int,
                    bitflip_rate: float, flip_keys, backend: str | None,
                    decode: bool, key_mode: str | None = None,
                    batch_shapes=None,
-                   fault_model: FaultModel | None = None) -> list:
+                   fault_model: FaultModel | None = None,
+                   interpret: bool | None = None) -> list:
     backend, key_mode = _check_modes(backend, key_mode)
     n = len(nets)
     if n == 0:
@@ -681,7 +689,8 @@ def _dispatch_many(nets, values_seq, keys, bitstream_length: int,
                          float(bitflip_rate), backend == "compiled_pallas",
                          decode, key_mode=key_mode, batch_shapes=batch_shapes,
                          scalar_names=scalar_names, fault_model=fault_model,
-                         megakernel=backend == "compiled_megakernel")
+                         megakernel=backend == "compiled_megakernel",
+                         interpret=interpret)
     return list(outs)
 
 
@@ -722,6 +731,58 @@ def execute_bank(bank: BankPlan, values_seq, keys, bitstream_length: int,
     caller arrays); only pass it when the key rows are call-owned scratch,
     like the serve engine's per-batch stacks.
     """
+    fn, args, kw = _bank_call(bank, values_seq, keys, bitstream_length,
+                              active, bitflip_rate, flip_keys, backend,
+                              key_mode, batch_shapes, decode, donate,
+                              fault_model, interpret)
+    if device is not None:
+        with obs.span("exec.device_transfer", device=str(device)):
+            args = _with_keys(args, lambda k: jax.device_put(k, device))
+    # NOTE: the dispatch span measures host time to *enqueue* the jitted
+    # program (plus trace/lower cost on a cache miss) — jax dispatch is
+    # async, so device compute lands in the caller's block/reap interval.
+    with obs.span("exec.dispatch", bank=bank.name, slots=bank.n_members,
+                  bitstream_length=bitstream_length), _donation_quiet(donate):
+        outs = fn(*args, **kw)
+    return list(outs)
+
+
+def precompile_bank(bank: BankPlan, values_seq, keys, bitstream_length: int,
+                    *, active=None, bitflip_rate: float = 0.0,
+                    flip_keys=None, backend: str | None = None,
+                    key_mode: str | None = None, batch_shapes=None,
+                    decode: bool = False, device=None, donate: bool = False,
+                    fault_model: FaultModel | None = None,
+                    interpret: bool | None = None) -> None:
+    """Trace, lower and compile the program ``execute_bank`` would run with
+    the same arguments, without running it or touching ``device``.
+
+    Raises what building the program raises — bad request values, a trace
+    error, a kernel the compiler refuses — and nothing a device does.  The
+    executable lands in jit's cache, so an ``execute_bank`` call with the
+    same arguments only transfers and enqueues.  The serve engine calls it
+    after a failed launch, to tell a program's errors from a device's.
+    """
+    fn, args, kw = _bank_call(bank, values_seq, keys, bitstream_length,
+                              active, bitflip_rate, flip_keys, backend,
+                              key_mode, batch_shapes, decode, donate,
+                              fault_model, interpret)
+    if device is not None:
+        # Stand-ins committed to ``device``: the program placed as
+        # execute_bank's device_put places it, with no transfer.
+        sharding = jax.sharding.SingleDeviceSharding(device)
+        args = _with_keys(args, lambda k: jax.ShapeDtypeStruct(
+            k.shape, k.dtype, sharding=sharding))
+    with obs.span("exec.precompile", bank=bank.name, slots=bank.n_members,
+                  bitstream_length=bitstream_length), _donation_quiet(donate):
+        fn.trace(*args, **kw).lower().compile()
+
+
+def _bank_call(bank, values_seq, keys, bitstream_length, active,
+               bitflip_rate, flip_keys, backend, key_mode, batch_shapes,
+               decode, donate, fault_model, interpret):
+    """Checked, packed arguments of one bank dispatch: ``(fn, args, kw)``,
+    the stacked key rows at ``args[2]`` and the fault keys at ``args[3]``."""
     backend, key_mode = _check_modes(backend, key_mode)
     if backend == "reference":
         raise ValueError("execute_bank runs compiled BankPlans; use "
@@ -738,47 +799,44 @@ def execute_bank(bank: BankPlan, values_seq, keys, bitstream_length: int,
         fault_model = _check_fault_args(bitflip_rate, fault_model, flip_keys,
                                         "flip_keys")
         flip_keys = _fault_flip_keys(flip_keys, n, bitflip_rate, fault_model)
-    if device is not None:
-        with obs.span("exec.device_transfer", device=str(device)):
-            keys = jax.device_put(keys, device)
-            if flip_keys is not None:
-                flip_keys = jax.device_put(flip_keys, device)
     args = (bank, values_seq, keys, flip_keys, bitstream_length,
             float(bitflip_rate), backend == "compiled_pallas", decode)
     kw = dict(key_mode=key_mode, batch_shapes=batch_shapes, active=active,
               scalar_names=scalar_names, fault_model=fault_model,
               megakernel=backend == "compiled_megakernel",
               interpret=interpret)
-    # NOTE: the dispatch span measures host time to *enqueue* the jitted
-    # program (plus trace/lower cost on a cache miss) — jax dispatch is
-    # async, so device compute lands in the caller's block/reap interval.
-    with obs.span("exec.dispatch", bank=bank.name, slots=n,
-                  bitstream_length=bitstream_length):
-        if donate:
-            # Donation is best-effort: when no output can alias a key-row
-            # buffer (the common case — outputs are packed words, not keys)
-            # XLA ignores it and jax warns; that advisory is noise on a hot
-            # serving path.
-            with warnings.catch_warnings():
-                warnings.filterwarnings("ignore",
-                                        message="Some donated buffers were not")
-                outs = _execute_bank_donating(*args, **kw)
-        else:
-            outs = _execute_bank(*args, **kw)
-    return list(outs)
+    return (_execute_bank_donating if donate else _execute_bank), args, kw
+
+
+def _with_keys(args: tuple, f) -> tuple:
+    """``args`` with ``f`` applied to its key rows and fault keys."""
+    keys, flip_keys = args[2], args[3]
+    return (*args[:2], f(keys), None if flip_keys is None else f(flip_keys),
+            *args[4:])
+
+
+@contextlib.contextmanager
+def _donation_quiet(donate: bool):
+    # Donation is best-effort: when no output can alias a key-row buffer
+    # (the common case — outputs are packed words, not keys) XLA ignores it
+    # and jax warns; that advisory is noise on a hot serving path.
+    if not donate:
+        yield
+        return
+    with warnings.catch_warnings():
+        warnings.filterwarnings("ignore", message="Some donated buffers were not")
+        yield
 
 
 # ---------------------------- host-side key staging --------------------------------
 
 def _key_data_host(k) -> np.ndarray:
     # The public unwrap (jax.random.key_data) dispatches an XLA op per key —
-    # at serving rates that is the single largest per-batch host cost.  The
-    # raw buffer is directly reachable on current jax; fall back to the
-    # public path if the internal layout ever changes.
-    base = getattr(k, "_base_array", None)
-    if base is not None:
-        return np.asarray(base)
-    return np.asarray(jax.random.key_data(k))
+    # at serving rates that is the single largest per-batch host cost.  A
+    # typed key's raw buffer is reachable directly (same data, same bits).
+    # Raw uint32 keys have no such buffer: the AttributeError sends
+    # _stack_keys to its jnp.stack path.
+    return np.asarray(k._base_array)
 
 
 def _stack_keys(keys: list):

@@ -19,7 +19,7 @@ from . import obs
 from .dispatch import (_as_f32, _check_fault_args, _check_modes, _dispatch,
                        _dispatch_binary, _dispatch_many, _execute_compiled,
                        _normalize_batch_shapes, _normalize_keys, _stack_keys,
-                       execute_bank)
+                       execute_bank, precompile_bank)
 from .faults import FaultModel
 from .gates import Netlist
 from .plan import BankPlan, ExecutionPlan
@@ -412,7 +412,8 @@ def _run_many(reqs: "list[ExecRequest]", device=None,
                           shared.bitstream_length, rate, flip_keys,
                           shared.backend, shared.decode,
                           key_mode=shared.key_mode,
-                          batch_shapes=batch_shapes, fault_model=model)
+                          batch_shapes=batch_shapes, fault_model=model,
+                          interpret=shared.interpret)
 
 
 def _run_template(reqs, bank: BankPlan, active=None, device=None,
@@ -420,6 +421,28 @@ def _run_template(reqs, bank: BankPlan, active=None, device=None,
                   options: ExecOptions | None = None) -> list:
     """Slot-aligned template execution: ``reqs[i]`` feeds template slot ``i``
     (``None`` = unbound slot, masked out)."""
+    args, kw = _template_args(reqs, bank, active, options)
+    return execute_bank(*args, device=device, donate=donate, **kw)
+
+
+def precompile(slot_reqs, *, template: BankPlan, active=None, device=None,
+               donate: bool = False,
+               options: ExecOptions | None = None) -> None:
+    """Compile what ``run(slot_reqs, template=...)`` with the same arguments
+    would run, without running it (``dispatch.precompile_bank``).
+
+    Raises the program's own errors — bad request values, a trace or
+    lowering error, a kernel the compiler refuses — and none of a device's;
+    a ``run`` with the same arguments finds the executable in jit's cache.
+    """
+    args, kw = _template_args(list(slot_reqs), template, active, options)
+    precompile_bank(*args, device=device, donate=donate, **kw)
+
+
+def _template_args(reqs, bank: BankPlan, active,
+                   options: ExecOptions | None) -> "tuple[tuple, dict]":
+    """``execute_bank``'s arguments for slot-aligned requests, bar
+    ``device`` and ``donate``."""
     n = bank.n_members
     if len(reqs) != n:
         raise ValueError(f"run: got {len(reqs)} slot requests for {n} slots")
@@ -454,14 +477,13 @@ def _run_template(reqs, bank: BankPlan, active=None, device=None,
                 raise ValueError("fault injection requires a flip_key on "
                                  "every request")
             flip_rows[i] = r.options.flip_key
-    return execute_bank(
-        bank, values_seq, _stack_keys(key_rows), shared.bitstream_length,
-        active=active, bitflip_rate=rate,
-        flip_keys=_stack_keys(flip_rows) if need_keys else None,
-        backend=shared.backend, key_mode=shared.key_mode,
-        batch_shapes=batch_shapes, decode=shared.decode,
-        device=device, donate=donate, fault_model=model,
-        interpret=shared.interpret)
+    return ((bank, values_seq, _stack_keys(key_rows),
+             shared.bitstream_length),
+            dict(active=active, bitflip_rate=rate,
+                 flip_keys=_stack_keys(flip_rows) if need_keys else None,
+                 backend=shared.backend, key_mode=shared.key_mode,
+                 batch_shapes=batch_shapes, decode=shared.decode,
+                 fault_model=model, interpret=shared.interpret))
 
 
 def run(request_or_requests, *, template: BankPlan | None = None,

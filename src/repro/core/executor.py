@@ -64,8 +64,9 @@ from .faults import FaultModel, apply_faults  # noqa: F401
 from . import obs  # noqa: F401  (re-export: executor.obs.Trace etc.)
 from .exec_api import (_MANY_TAIL, ExecOptions, ExecRequest,  # noqa: F401
                        _common_options, _many_shim, _many_tail, _run_many,
-                       _run_one, _run_template, execute, execute_binary,
-                       execute_many, execute_value, execute_value_many, run)
+                       _run_one, _run_template, execute,
+                       execute_binary, execute_many, execute_value,
+                       execute_value_many, precompile, run)
 from .streams import (_BACKENDS, _KEY_MODES, DEFAULT_BACKEND,  # noqa: F401
                       DEFAULT_KEY_MODE, _gen_bank_streams, _gen_pi_streams,
                       _pi_shape, _stack_table_values)
@@ -74,5 +75,5 @@ __all__ = [
     "DEFAULT_BACKEND", "DEFAULT_KEY_MODE", "ExecOptions", "ExecRequest",
     "FaultModel", "execute", "execute_bank", "execute_binary",
     "execute_many", "execute_value", "execute_value_many",
-    "generate_bank_streams", "obs", "run",
+    "generate_bank_streams", "obs", "precompile", "run",
 ]

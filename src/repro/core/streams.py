@@ -67,7 +67,8 @@ def _gen_pi_streams(pis, values: dict[str, jax.Array], key: jax.Array,
                     batch_shape: tuple[int, ...] | None = None,
                     use_pallas: bool = False,
                     table: StreamTable | None = None,
-                    word_window: tuple | None = None) -> dict[str, jax.Array]:
+                    word_window: tuple | None = None,
+                    interpret: bool | None = None) -> dict[str, jax.Array]:
     """Generate packed streams for every PI, honoring correlation groups and
     independent-copy indices.  ``pis`` is any sequence of PrimaryInput.
 
@@ -81,6 +82,7 @@ def _gen_pi_streams(pis, values: dict[str, jax.Array], key: jax.Array,
     The chunked streaming executor regenerates each chunk's PI words this way
     instead of holding full-length streams live.  The legacy threefry
     discipline draws all words in one monolithic call and cannot window.
+    ``interpret`` forwards to the Pallas SNG kernel (``use_pallas``).
     """
     # Under the compiled backends this body runs at jit-trace time, so the
     # span measures lowering cost (a cache-miss-only host cost), not
@@ -88,11 +90,12 @@ def _gen_pi_streams(pis, values: dict[str, jax.Array], key: jax.Array,
     with obs.span("streams.gen_pi", key_mode=key_mode, trace_time=True):
         return _gen_pi_streams_impl(pis, values, key, bitstream_length,
                                     key_mode, batch_shape, use_pallas, table,
-                                    word_window)
+                                    word_window, interpret)
 
 
 def _gen_pi_streams_impl(pis, values, key, bitstream_length, key_mode,
-                         batch_shape, use_pallas, table, word_window):
+                         batch_shape, use_pallas, table, word_window,
+                         interpret):
     shape = _pi_shape(values, batch_shape)
     if key_mode == "batched":
         if table is None:
@@ -103,7 +106,8 @@ def _gen_pi_streams_impl(pis, values, key, bitstream_length, key_mode,
         words = bs.generate_batch(key, ps, bitstream_length,
                                   lanes=jnp.asarray(table.lanes, jnp.uint32),
                                   use_pallas=use_pallas,
-                                  word_window=word_window)
+                                  word_window=word_window,
+                                  interpret=interpret)
         return {name: words[i] for i, name in enumerate(table.names)}
     if word_window is not None:
         raise ValueError("word_window requires key_mode='batched': legacy "
@@ -147,7 +151,8 @@ def _gen_pi_streams_impl(pis, values, key, bitstream_length, key_mode,
 
 def _gen_bank_streams(bank: BankPlan, values_seq, keys, bitstream_length: int,
                       key_mode: str, use_pallas: bool,
-                      batch_shapes, active=None) -> list[dict[str, jax.Array]]:
+                      batch_shapes, active=None,
+                      interpret: bool | None = None) -> list[dict[str, jax.Array]]:
     """Per-member PI streams for a whole bank (list indexed by member).
 
     Batched key mode is the paper's bulk BtoS pass bank-wide: every member's
@@ -162,6 +167,7 @@ def _gen_bank_streams(bank: BankPlan, values_seq, keys, bitstream_length: int,
     words (value-0.0 constants, nearly free), just enough to keep the merged
     logic passes well-formed.  Active members' streams are untouched by the
     masking, so padded execution stays bit-identical per bound slot.
+    ``interpret`` forwards to the Pallas SNG kernel (``use_pallas``).
     """
     # Like _gen_pi_streams: under jit this span measures trace/lowering
     # cost (cache misses only), not per-call runtime.
@@ -169,11 +175,12 @@ def _gen_bank_streams(bank: BankPlan, values_seq, keys, bitstream_length: int,
                   trace_time=True):
         return _gen_bank_streams_impl(bank, values_seq, keys,
                                       bitstream_length, key_mode, use_pallas,
-                                      batch_shapes, active)
+                                      batch_shapes, active, interpret)
 
 
 def _gen_bank_streams_impl(bank, values_seq, keys, bitstream_length,
-                           key_mode, use_pallas, batch_shapes, active):
+                           key_mode, use_pallas, batch_shapes, active,
+                           interpret):
     n = bank.n_members
     streams: list[dict[str, jax.Array]] = [{} for _ in range(n)]
     w = bs.n_words(bitstream_length)
@@ -215,7 +222,8 @@ def _gen_bank_streams_impl(bank, values_seq, keys, bitstream_length,
         ps = jnp.concatenate([e[1] for e in entries])
         seeds = jnp.concatenate([e[2] for e in entries])
         words = bs.generate_batch_seeded(seeds, ps, bitstream_length,
-                                         use_pallas=use_pallas)
+                                         use_pallas=use_pallas,
+                                         interpret=interpret)
         off = 0
         for i, ps_i, _ in entries:
             names = bank.members[i].stream_table.names

@@ -10,16 +10,61 @@ keeping it in plain jnp means the Pallas kernel and the ref.py oracle compute
 """
 from __future__ import annotations
 
+import warnings
+
 import jax
 import jax.numpy as jnp
 
 WORD_BITS = 32
 
 
-def on_tpu() -> bool:
-    """Whether the default backend is a real TPU (Pallas compiles natively);
-    everywhere else the kernels run in interpret mode."""
-    return jax.default_backend() == "tpu"
+class InterpretModeWarning(UserWarning):
+    """A Pallas kernel was asked to pick its own mode off the chip and runs
+    in the (slow, host-side) interpreter instead of compiled."""
+
+
+def resolve_interpret(interpret: bool | None) -> bool:
+    """Pallas interpret flag for one kernel call.
+
+    An explicit ``True``/``False`` passes through (tests ask for
+    ``interpret=True``).  ``None`` compiles the kernel on a TPU; on any other
+    default backend it falls back to interpret mode and says so with an
+    ``InterpretModeWarning`` — a chip that failed to initialise must not
+    pass silently for a compiled run.
+    """
+    if interpret is not None:
+        return interpret
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    warnings.warn(
+        f"Pallas kernel runs in interpret mode: the default JAX backend is "
+        f"{backend!r}, not 'tpu' (pass interpret=True to ask "
+        "for it explicitly)", InterpretModeWarning, stacklevel=3)
+    return True
+
+
+def lane_tiles(x: jax.Array) -> jax.Array:
+    """View packed words of any shape as ``(lead, sublanes, lanes)`` tiles.
+
+    The longer of the last two axes goes on the lanes, so a frame-sized
+    batch of short streams — ``(76800, 32)`` at BL=1024 — is handed to a
+    kernel as ``(32, 76800)`` instead of a 32-lane array padded to 128
+    lanes (4x the HBM).  Every kernel on these tiles is elementwise, so the
+    view changes no bit; ``from_lane_tiles`` undoes it.
+    """
+    if x.ndim < 2:
+        return x.reshape(1, 1, -1)
+    r, w = x.shape[-2:]
+    x3 = x.reshape(-1, r, w)
+    return jnp.swapaxes(x3, 1, 2) if w < r else x3
+
+
+def from_lane_tiles(y: jax.Array, shape: tuple[int, ...]) -> jax.Array:
+    """Inverse of ``lane_tiles`` for an array of logical ``shape``."""
+    if len(shape) >= 2 and shape[-1] < shape[-2]:
+        y = jnp.swapaxes(y, -1, -2)
+    return y.reshape(shape)
 
 
 def hash_u32(x: jax.Array) -> jax.Array:

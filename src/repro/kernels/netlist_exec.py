@@ -48,13 +48,11 @@ def _apply_pass(op: str, ins: list[jax.Array], use_pallas: bool,
     the pass equals materializing the NOT's output stream).  On the Pallas
     path the mask folds into the kernel itself (an in-register read), so no
     separate full-tensor complement op ever materializes; ``interpret``
-    forwards to ``packed_logic`` (None = auto-detect off-TPU).
+    forwards to ``packed_logic`` (None: compiled on a TPU).
     """
     if use_pallas and op in _PALLAS_OPS and ins[0].ndim >= 2:
-        shape = ins[0].shape
-        flat = [x.reshape(-1, shape[-1]) for x in ins]
-        return packed_logic(_PALLAS_OPS[op], *flat, neg=tuple(neg),
-                            interpret=interpret).reshape(shape)
+        return packed_logic(_PALLAS_OPS[op], *ins, neg=tuple(neg),
+                            interpret=interpret)
     if any(neg):
         ins = [~x if nb else x for x, nb in zip(ins, neg)]
     if op == "BUFF":
@@ -84,9 +82,10 @@ def run_combinational(plan: ExecutionPlan, env: dict[str, jax.Array],
 
     ``megakernel=True`` lowers the whole plan into ONE Pallas kernel
     (``plan_megakernel``) when it can — homogeneous PI shapes and a
-    liveness-annotated plan — silently falling back to the per-pass path
-    otherwise.  Fault injection faults individual pass outputs, which the
-    fused kernel never materializes, so the combination is rejected.
+    liveness-annotated plan — falling back to the per-pass path otherwise
+    (counted as ``megakernel.declined`` in ``obs.REGISTRY``).  Fault
+    injection faults individual pass outputs, which the fused kernel never
+    materializes, so the combination is rejected.
 
     The per-pass path releases dead intermediates as it goes: after each
     pass, every node in ``cop.free_after`` (computed by the compiler's

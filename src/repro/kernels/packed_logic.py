@@ -11,9 +11,12 @@ absorbed lone NOT costs zero extra passes AND zero extra XLA ops — the
 complement happens on the VMEM-resident tile, not as a separate full-tensor
 pass before the pallas_call.
 
-Block shapes: (BM, BW) words; BM a multiple of 8 rows, BW a multiple of 128
-lanes to match the (8, 128) vreg tiling for 32-bit types.  ``interpret=None``
-(the default) auto-selects: compiled on TPU, interpret mode everywhere else.
+Operands of any shape are viewed as ``common.lane_tiles`` — the longer of
+the last two axes on the 128 lanes — so a frame of short streams stays
+lane-dense in HBM and VMEM.  Block shapes: (8k sublanes, 128k lanes), or the
+full extent, to match the (8, 128) vreg tiling for 32-bit types.
+``interpret=None`` (the default) compiles on a TPU and elsewhere interprets
+with a warning (``common.resolve_interpret``).
 """
 from __future__ import annotations
 
@@ -23,7 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .common import on_tpu
+from .common import from_lane_tiles, lane_tiles, resolve_interpret
 
 _OPS1 = {"not"}
 _OPS2 = {"and", "nand", "or", "nor", "xor"}
@@ -61,26 +64,23 @@ def _kernel3(op, neg, a_ref, b_ref, s_ref, o_ref):
     o_ref[...] = (a & s) | (b & ~s)  # fused scaled addition
 
 
-@functools.partial(jax.jit, static_argnames=("op", "block_rows", "block_words",
+@functools.partial(jax.jit, static_argnames=("op", "block_sub", "block_lanes",
                                              "interpret", "neg"))
-def packed_logic(op: str, *args: jax.Array, block_rows: int = 8,
-                 block_words: int = 128, interpret: bool | None = None,
+def packed_logic(op: str, *args: jax.Array, block_sub: int = 32,
+                 block_lanes: int = 1024, interpret: bool | None = None,
                  neg: tuple[bool, ...] = ()) -> jax.Array:
-    """Apply a packed logic op over (rows, words) uint32 tensors.
+    """Apply a packed logic op over same-shape uint32 word tensors.
 
     ``neg[j]`` complements operand ``j`` inside the kernel before the op
     (``CompiledOp.neg``, the absorbed-lone-NOT mask); ``()`` means none.
-    ``interpret=None`` resolves to interpret mode unless running on a real
-    TPU (``common.on_tpu``).
+    ``interpret`` resolves through ``common.resolve_interpret``.
     """
-    if interpret is None:
-        interpret = not on_tpu()
-    a = args[0]
-    rows, words = a.shape
-    bm = min(block_rows, rows)
-    bw = min(block_words, words)
-    grid = (pl.cdiv(rows, bm), pl.cdiv(words, bw))
-    spec = pl.BlockSpec((bm, bw), lambda i, j: (i, j))
+    shape = args[0].shape
+    tiles = [lane_tiles(x) for x in args]
+    lead, sub, lanes = tiles[0].shape
+    sub_blk, lane_blk = min(block_sub, sub), min(block_lanes, lanes)
+    grid = (lead, pl.cdiv(sub, sub_blk), pl.cdiv(lanes, lane_blk))
+    spec = pl.BlockSpec((None, sub_blk, lane_blk), lambda g, i, j: (g, i, j))
 
     if op in _OPS1:
         kernel, n_in = _kernel1, 1
@@ -97,11 +97,13 @@ def packed_logic(op: str, *args: jax.Array, block_rows: int = 8,
                          f"for {n_in} operands")
     full_neg = tuple(neg) if neg else (False,) * n_in
 
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(kernel, op, full_neg),
         grid=grid,
         in_specs=[spec] * n_in,
         out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct((rows, words), jnp.uint32),
-        interpret=interpret,
-    )(*args)
+        out_shape=jax.ShapeDtypeStruct(tiles[0].shape, jnp.uint32),
+        interpret=resolve_interpret(interpret),
+        name="packed_logic",
+    )(*tiles)
+    return from_lane_tiles(out, shape)

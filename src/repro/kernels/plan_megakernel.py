@@ -4,7 +4,9 @@ The per-pass path (``netlist_exec``) issues one kernel per fused pass, so
 every intermediate node stream round-trips through HBM-equivalent buffers
 between passes.  This module lowers an entire combinational plan (or the
 combinational body of a sequential plan's scan step) into a single
-``pallas_call`` gridded over ``(row_tiles, word_tiles)``:
+``pallas_call`` gridded over lane-dense ``(sublane_tiles, lane_tiles)`` of
+the streams (``common.lane_tiles``: a frame of short streams puts its
+pixels on the lanes):
 
   * each tile's PI streams load once into a VMEM scratch *pool* sized by the
     liveness stage's ``plan.max_live`` — NOT by node count — and every
@@ -22,6 +24,12 @@ never recycles a slot while its node is still live (``stages.assign_liveness``
 releases a pass's dying inputs only after the pass, so batched gates cannot
 clobber a sibling's operand).  Off-TPU the kernel runs in interpret mode,
 bit-identical to the jnp per-pass path.
+
+A plan the kernel cannot take falls back to the per-pass path, and says so:
+every decline adds to the ``megakernel.declined`` counter of
+``obs.REGISTRY`` (with a ``megakernel.declined`` event on the current
+trace), every lowering to ``megakernel.lowered``.  Both count traces, not
+calls: a cached jit program reruns without tracing.
 """
 from __future__ import annotations
 
@@ -33,8 +41,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..core import bitstream as bs
+from ..core import obs
 from ..core.plan import FUSED_MUX, ExecutionPlan
-from .common import on_tpu
+from .common import from_lane_tiles, lane_tiles, resolve_interpret
 
 #: program instruction: (op, neg, in_slot_rows, out_slots) — all static.
 
@@ -87,22 +96,29 @@ def _kernel(program, pi_slots, out_slots, pi_ref, out_ref, scratch):
         out_ref[k] = scratch[s]
 
 
+def _decline(plan: ExecutionPlan, reason: str) -> None:
+    obs.REGISTRY.inc("megakernel.declined")
+    obs.event("megakernel.declined", plan=plan.name, reason=reason)
+
+
 def combinational_megakernel(plan: ExecutionPlan,
                              env: dict[str, jax.Array], *,
-                             block_rows: int = 8, block_words: int = 128,
+                             block_sub: int = 8, block_lanes: int = 128,
                              interpret: bool | None = None,
                              ) -> dict[str, jax.Array] | None:
     """Run a combinational plan as one fused Pallas kernel.
 
     ``env`` maps every stream/state PI name to its packed words (any common
-    shape; the kernel flattens to (rows, words)).  Returns the plan's
-    observable streams — outputs and state drivers, aliases resolved — or
-    ``None`` when the plan cannot lower (no liveness info, or heterogeneous
-    PI shapes, as in a merged bank serving mixed batch shapes); the caller
-    then falls back to the per-pass path.
+    shape; the kernel views it as ``common.lane_tiles``).  Returns the
+    plan's observable streams — outputs and state drivers, aliases resolved
+    — or ``None`` when the plan cannot lower (no liveness info, or
+    heterogeneous PI shapes, as in a merged bank serving mixed batch
+    shapes); the caller then falls back to the per-pass path, and the
+    decline is counted (module docstring).
     """
     prog = _plan_program(plan)
     if prog is None:
+        _decline(plan, "no liveness assignment")
         return None
     program, slot_of = prog
 
@@ -117,50 +133,54 @@ def combinational_megakernel(plan: ExecutionPlan,
 
     pi_names = [pi.name for pi, s in zip(plan.pis, plan.pi_slots) if s >= 0]
     shapes = {env[nm].shape for nm in pi_names}
-    if len(shapes) != 1:
+    if len(shapes) > 1:
+        _decline(plan, "heterogeneous PI shapes")
+        return None
+    if plan.max_live == 0 or not pi_names:
+        _decline(plan, "no stream PIs")
         return None
     (shape,) = shapes
-    if plan.max_live == 0 or not pi_names:
-        return None
 
-    words = shape[-1] if len(shape) >= 1 else 1
-    rows = 1
-    for d in shape[:-1]:
-        rows *= d
-    stacked = jnp.stack([env[nm].reshape(rows, words) for nm in pi_names])
+    tiles = [lane_tiles(env[nm]) for nm in pi_names]
+    tile_shape = tiles[0].shape
+    stacked = jnp.stack([t.reshape(-1, tile_shape[-1]) for t in tiles])
     out = _megakernel_call(
         plan, tuple(slot_of[nm] for nm in pi_names),
         tuple(slot_of[nm] for nm in out_names), len(out_names),
-        stacked, block_rows, block_words, interpret)
-    return {nm: out[out_names.index(alias.get(nm, nm))].reshape(shape)
+        stacked, block_sub, block_lanes, interpret)
+    obs.REGISTRY.inc("megakernel.lowered")
+    return {nm: from_lane_tiles(
+                out[out_names.index(alias.get(nm, nm))].reshape(tile_shape),
+                shape)
             for nm in (*plan.outputs, *plan.state_drivers)}
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "plan", "pi_slots", "out_slots", "n_out", "block_rows", "block_words",
+    "plan", "pi_slots", "out_slots", "n_out", "block_sub", "block_lanes",
     "interpret"))
 def _megakernel_call(plan: ExecutionPlan, pi_slots, out_slots, n_out: int,
-                     stacked: jax.Array, block_rows: int, block_words: int,
+                     stacked: jax.Array, block_sub: int, block_lanes: int,
                      interpret: bool | None) -> jax.Array:
-    """The jitted pallas_call: (P, rows, words) PI stack -> (O, rows, words).
+    """The jitted pallas_call: (P, sub, lanes) PI stack -> (O, sub, lanes).
 
     The plan is a static arg (interned, identity-hashed), so the slot program
-    rebuilds only per plan per shape — one trace, one kernel.
+    rebuilds only per plan per shape — one trace, one kernel.  A block holds
+    ``P + max_live + O`` tiles of ``block_sub x block_lanes`` words in VMEM.
     """
-    if interpret is None:
-        interpret = not on_tpu()
     program, _ = _plan_program(plan)
-    p, rows, words = stacked.shape
-    bm = min(block_rows, rows)
-    bw = min(block_words, words)
-    grid = (pl.cdiv(rows, bm), pl.cdiv(words, bw))
+    p, sub, lanes = stacked.shape
+    sub_blk, lane_blk = min(block_sub, sub), min(block_lanes, lanes)
+    grid = (pl.cdiv(sub, sub_blk), pl.cdiv(lanes, lane_blk))
     kernel = functools.partial(_kernel, program, pi_slots, out_slots)
     return pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[pl.BlockSpec((p, bm, bw), lambda i, j: (0, i, j))],
-        out_specs=pl.BlockSpec((n_out, bm, bw), lambda i, j: (0, i, j)),
-        out_shape=jax.ShapeDtypeStruct((n_out, rows, words), jnp.uint32),
-        scratch_shapes=[pltpu.VMEM((plan.max_live, bm, bw), jnp.uint32)],
-        interpret=interpret,
+        in_specs=[pl.BlockSpec((p, sub_blk, lane_blk), lambda i, j: (0, i, j))],
+        out_specs=pl.BlockSpec((n_out, sub_blk, lane_blk),
+                               lambda i, j: (0, i, j)),
+        out_shape=jax.ShapeDtypeStruct((n_out, sub, lanes), jnp.uint32),
+        scratch_shapes=[pltpu.VMEM((plan.max_live, sub_blk, lane_blk),
+                                   jnp.uint32)],
+        interpret=resolve_interpret(interpret),
+        name="plan_megakernel",
     )(stacked)

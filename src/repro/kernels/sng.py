@@ -30,7 +30,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from . import ref
-from .common import WORD_BITS, hash_u32, mix_seed, on_tpu, threshold_u32
+from .common import (WORD_BITS, hash_u32, mix_seed, resolve_interpret,
+                     threshold_u32)
 
 
 def lane_seeds(seed: jax.Array, lanes: jax.Array) -> jax.Array:
@@ -39,19 +40,22 @@ def lane_seeds(seed: jax.Array, lanes: jax.Array) -> jax.Array:
                     jnp.asarray(lanes, jnp.uint32))
 
 
-def _kernel(seed_ref, thr_ref, o_ref, *, n_words: int, be: int):
+def _kernel(seed_ref, thr_ref, o_ref, *, n_words: int, be: int, rb: int):
+    # Word-major tile: elements run along the 128 lanes, words along the
+    # sublanes, so a row's thresholds broadcast down the sublanes and the
+    # (W, be) tile stays lane-dense for any stream length.
     j = pl.program_id(1)
-    s = seed_ref[0]                                       # mixed per-row seed
-    thr = thr_ref[0]                                      # (be,)
-    elem = (j * be + jnp.arange(be, dtype=jnp.uint32))    # global element ids
-    base = (elem[:, None] * jnp.uint32(n_words)
-            + jnp.arange(n_words, dtype=jnp.uint32)[None, :]) * jnp.uint32(
-                WORD_BITS)                                # (be, W) bit counters
-    acc = jnp.zeros((be, n_words), jnp.uint32)
-    for t in range(WORD_BITS):
-        r = hash_u32((base + jnp.uint32(t)) ^ s)
-        acc = acc | ((r < thr[:, None]).astype(jnp.uint32) << jnp.uint32(t))
-    o_ref[...] = acc[None]
+    elem = j * be + jax.lax.broadcasted_iota(jnp.uint32, (n_words, be), 1)
+    word = jax.lax.broadcasted_iota(jnp.uint32, (n_words, be), 0)
+    base = (elem * jnp.uint32(n_words) + word) * jnp.uint32(WORD_BITS)
+    for r in range(rb):                                   # rows of this block
+        s = seed_ref[pl.ds(r, 1), :]                      # (1, be) row seed
+        thr = thr_ref[pl.ds(r, 1), :]                     # (1, be)
+        acc = jnp.zeros((n_words, be), jnp.uint32)
+        for t in range(WORD_BITS):
+            h = hash_u32((base + jnp.uint32(t)) ^ s)
+            acc = acc | ((h < thr).astype(jnp.uint32) << jnp.uint32(t))
+        o_ref[r] = acc
 
 
 @functools.partial(jax.jit, static_argnames=("n_words", "use_pallas",
@@ -68,7 +72,19 @@ def sng_words(row_seeds: jax.Array, thr: jax.Array, n_words: int,
     equal seed share their uniforms (correlation groups decode exact |a-b|
     under XOR).  ``thr``: (N, B) uint32 compare thresholds.  The jnp fallback
     (``use_pallas=False``, the executor default) and the Pallas kernel are
-    bit-identical; ``interpret=None`` auto-selects interpret mode off-TPU.
+    bit-identical; ``interpret`` resolves through ``common.resolve_interpret``
+    (None: compiled on a TPU, elsewhere interpreted with a warning).
+
+    The kernel's blocks meet the TPU's (8, 128) tiling rule at any table
+    size: each grid step reads ``min(8, N)`` rows — their thresholds as a
+    ``(rows, block_elems)`` tile, their seeds as one such tile repeated along
+    the lanes (Mosaic cannot broadcast a scalar over sublanes and lanes at
+    once) — so its VMEM use does not grow with the table, and writes those
+    rows word-major, ``(N, W, B)`` with elements on the lanes.  The final
+    axis swap to ``(N, B, W)`` and ``common.lane_tiles``' swap back compile
+    to layout bitcasts, not copies (``tests/test_tpu_compile.py``).
+    ``block_elems`` must be a multiple of 128, or cover ``B``, on the chip;
+    interpret mode takes any block.
 
     ``word_offset``/``total_words`` request a word *window* of a conceptual
     ``total_words``-long stream (see ``ref.sng_words_ref``) — exact because
@@ -92,16 +108,19 @@ def sng_words(row_seeds: jax.Array, thr: jax.Array, n_words: int,
                                  word_offset=word_offset, total_words=total)
     n, b = thr.shape
     be = min(block_elems, b)
-    kernel = functools.partial(_kernel, n_words=n_words, be=be)
-    return pl.pallas_call(
-        kernel,
-        grid=(n, pl.cdiv(b, be)),
-        in_specs=[pl.BlockSpec((1,), lambda i, j: (i,)),
-                  pl.BlockSpec((1, be), lambda i, j: (i, j))],
-        out_specs=pl.BlockSpec((1, be, n_words), lambda i, j: (i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, b, n_words), jnp.uint32),
-        interpret=not on_tpu() if interpret is None else interpret,
-    )(row_seeds.astype(jnp.uint32), thr)
+    rb = min(8, n)
+    grid = (pl.cdiv(n, rb), pl.cdiv(b, be))
+    words = pl.pallas_call(
+        functools.partial(_kernel, n_words=n_words, be=be, rb=rb),
+        grid=grid,
+        in_specs=[pl.BlockSpec((rb, be), lambda i, j: (i, 0)),
+                  pl.BlockSpec((rb, be), lambda i, j: (i, j))],
+        out_specs=pl.BlockSpec((rb, n_words, be), lambda i, j: (i, 0, j)),
+        out_shape=jax.ShapeDtypeStruct((n, n_words, b), jnp.uint32),
+        interpret=resolve_interpret(interpret),
+        name="sng_words",
+    )(jnp.broadcast_to(row_seeds.astype(jnp.uint32)[:, None], (n, be)), thr)
+    return jnp.swapaxes(words, 1, 2)
 
 
 @functools.partial(jax.jit, static_argnames=("bitstream_length", "seed",

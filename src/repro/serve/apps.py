@@ -69,3 +69,40 @@ def circuit_request(net: Netlist, values: dict, key, bl: int = 256, *,
                      batch_shape=batch_shape, bitflip_rate=bitflip_rate,
                      flip_key=flip_key, fault_model=fault_model,
                      deadline_ms=deadline_ms)
+
+
+#: Bursty LIT + KDE traffic, ``(n_lit, n_kde)`` per burst: the composition
+#: shifts burst to burst but revisits earlier mixes, which is what the
+#: server's template bucketing rewards.
+LIT_KDE_BURSTS = ((3, 1), (1, 3), (3, 1), (2, 2), (1, 3), (3, 1), (2, 2),
+                  (1, 3))
+
+
+def lit_kde_bursts(rng, key, bl: int = 256, bursts=LIT_KDE_BURSTS) -> list:
+    """Requests of the bursty LIT + KDE trace, drawn from ``rng``/``key``.
+
+    Returns one list per burst of ``(app, request, exact)`` triples: a LIT
+    request evaluates one 9x9 window (81 pixels), a KDE request one pixel
+    against a ``KDE_N``-frame history, and ``exact`` is the float app's
+    value (``lit_exact`` / ``kde_exact``) for the same inputs.
+    """
+    import jax
+    import numpy as np
+
+    out = []
+    for n_lit, n_kde in bursts:
+        burst = []
+        for _ in range(n_lit):
+            a = rng.uniform(0.1, 0.9, size=(81,))
+            key, sub = jax.random.split(key)
+            burst.append(("lit", app_request("lit", sub, bl, a=a),
+                          float(core_apps.lit_exact(a))))
+        for _ in range(n_kde):
+            x_t = rng.uniform(0.2, 0.8)
+            hist = rng.uniform(0.2, 0.8, size=(core_apps.KDE_N,))
+            key, sub = jax.random.split(key)
+            burst.append(("kde", app_request("kde", sub, bl, x_t=x_t,
+                                             hist=hist),
+                          float(core_apps.kde_exact(np.asarray(x_t), hist))))
+        out.append(burst)
+    return out

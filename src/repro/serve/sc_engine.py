@@ -388,6 +388,7 @@ class BankServerStats:
     redispatched_requests: int = 0  # in-flight requests moved off a
     #                                 quarantined device (no retry budget)
     deadline_exceeded: int = 0    # tickets failed by their deadline_ms
+    failed_requests: int = 0      # tickets failed by an execution error
     exec_s: float = 0.0           # busy wall time (>=1 batch in flight)
     latencies_s: "deque[float]" = dataclasses.field(
         default_factory=lambda: deque(maxlen=LATENCY_WINDOW))
@@ -420,6 +421,7 @@ class BankServerStats:
             "quarantines": self.quarantines,
             "redispatched_requests": self.redispatched_requests,
             "deadline_exceeded": self.deadline_exceeded,
+            "failed_requests": self.failed_requests,
             "p50_ms": _percentile(lat, 0.50) * 1e3,
             "p99_ms": _percentile(lat, 0.99) * 1e3,
             "mean_ms": (sum(lat) / len(lat) * 1e3) if lat else 0.0,
@@ -874,9 +876,15 @@ class BankServer:
     def _launch(self, batch: _Batch, device) -> None:
         """Dispatch one batch asynchronously; resolve its tickets.
 
-        Dispatch-time failures (bad request values, trace errors) and
-        device-side failures (surfacing at finalize/``result()``) both run
-        the retry/circuit-breaker policy via ``_on_batch_failure``."""
+        Device failures — the ``fault_injector`` hook, errors raised while
+        the batch is placed or enqueued, and errors surfacing at
+        finalize/``result()`` — run the retry/circuit-breaker policy via
+        ``_on_batch_failure``.  When the dispatch raises, the server compiles
+        the same program again without the device (``executor.precompile``); if
+        that raises too — bad request values, a trace or lowering error, a
+        kernel the chip's compiler refuses — the error is the program's: it
+        fails the batch's tickets as that error, with no retry and no count
+        against the device (``_on_program_error``)."""
         tr = self.trace
         if tr is None:
             self._launch_impl(batch, device)
@@ -941,14 +949,25 @@ class BankServer:
             st.naive_live_peak = max(st.naive_live_peak, g.naive_live)
         dev_arg = device if multi and device is not self._default_device \
             else None
-        try:
-            if self.fault_injector is not None:
+        if self.fault_injector is not None:
+            try:
                 self.fault_injector(device, batch)
-            outs = executor.run(slot_reqs, template=bank, active=active,
-                                device=dev_arg,
-                                donate=self.donate, options=shared)
+            except Exception as exc:
+                self._on_batch_failure(batch, exc, device)
+                return
+        kw = dict(template=bank, active=active, device=dev_arg,
+                  donate=self.donate, options=shared)
+        try:
+            outs = executor.run(slot_reqs, **kw)
         except Exception as exc:
-            self._on_batch_failure(batch, exc, device)
+            # Whose error?  Compiling the same program without touching the
+            # device tells: if that fails too, the program is at fault.
+            try:
+                executor.precompile(slot_reqs, **kw)
+            except Exception as program_exc:
+                self._on_program_error(batch, program_exc, device)
+            else:
+                self._on_batch_failure(batch, exc, device)
             return
         batch.device = device
         batch.dispatched_at = t0
@@ -1073,20 +1092,9 @@ class BankServer:
         # wrapping (isinstance checks and tracebacks stay intact).
         if getattr(exc, "_bankserver_noted", False):
             return
-        note = (f"[BankServer] raised while executing a bank batch of "
-                f"{len(batch.pendings)} request(s) on {device}")
-        try:
-            if hasattr(exc, "add_note"):        # Python >= 3.11
-                exc.add_note(note)
-            else:                               # emulate PEP 678 storage
-                notes = getattr(exc, "__notes__", None)
-                if notes is None:
-                    notes = []
-                    exc.__notes__ = notes
-                notes.append(note)
-            exc._bankserver_noted = True
-        except Exception:                       # pragma: no cover - safety
-            pass
+        exc.add_note(f"[BankServer] raised while executing a bank batch of "
+                     f"{len(batch.pendings)} request(s) on {device}")
+        exc._bankserver_noted = True
 
     def _on_batch_failure(self, batch: _Batch, exc: BaseException,
                           device) -> None:
@@ -1098,6 +1106,27 @@ class BankServer:
         now = time.perf_counter()
         for p in batch.pendings:
             self._retry_or_fail(p, exc, now)
+
+    def _on_program_error(self, batch: _Batch, exc: BaseException,
+                          device) -> None:
+        """Fail every ticket of a batch whose program could not be built or
+        enqueued — no retry (the same program would fail again) and no
+        circuit-breaker count (the device did nothing wrong)."""
+        batch.finalized = True
+        self._note_exception(exc, batch, device)
+        for p in batch.pendings:
+            self._fail_request(p, exc)
+
+    def _fail_request(self, p: _Pending, exc: BaseException) -> None:
+        t = p.ticket
+        if t._error is not None:
+            return              # already settled (deadline hit mid-flight)
+        self._stats.failed_requests += 1
+        if self.trace is not None:
+            self.trace.metrics.inc("serve.failed_requests")
+            self.trace.event("serve.request_failed", seq=p.seq,
+                             error=type(exc).__name__)
+        t._fail(exc)
 
     def _retry_or_fail(self, p: _Pending, exc: BaseException,
                        now: float) -> None:
@@ -1117,10 +1146,7 @@ class BankServer:
                     self.trace.event("serve.retry", seq=p.seq,
                                      attempt=p.retries)
                 return
-        if self.trace is not None:
-            self.trace.event("serve.request_failed", seq=p.seq,
-                             error=type(exc).__name__)
-        t._fail(exc)
+        self._fail_request(p, exc)
 
     def _note_device_failure(self, device) -> None:
         n = self._consec_failures.get(device, 0) + 1

@@ -3,10 +3,9 @@
 NOTE: tests must see the single real CPU device — the 512-device
 XLA_FLAGS override belongs to launch/dryrun.py ONLY.
 
-``hypothesis`` is optional: when installed, the fast profile below is
-registered; when absent, property tests skip per-test via tests/_hyp.py.
+Property tests use ``hypothesis`` directly, under the fast profile below.
 
-``pytest-timeout`` is likewise optional: the ``timeout`` ini option in
+``pytest-timeout`` is optional: the ``timeout`` ini option in
 pyproject.toml guards the suite against a hung serving drive loop.  When
 the plugin is absent this conftest registers the option itself and enforces
 it with a SIGALRM fallback (main-thread, POSIX only — a no-op elsewhere,
@@ -18,24 +17,20 @@ import sys
 import threading
 
 import pytest
+from hypothesis import HealthCheck, settings
 
 # Make `import repro` work without an editable install.
 _SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
 if _SRC not in sys.path:
     sys.path.insert(0, _SRC)
 
-try:
-    from hypothesis import HealthCheck, settings
-except ModuleNotFoundError:
-    pass
-else:
-    settings.register_profile(
-        "fast",
-        max_examples=25,
-        deadline=None,
-        suppress_health_check=[HealthCheck.too_slow, HealthCheck.data_too_large],
-    )
-    settings.load_profile("fast")
+settings.register_profile(
+    "fast",
+    max_examples=25,
+    deadline=None,
+    suppress_health_check=[HealthCheck.too_slow, HealthCheck.data_too_large],
+)
+settings.load_profile("fast")
 
 try:
     import pytest_timeout  # noqa: F401

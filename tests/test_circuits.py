@@ -5,7 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hyp import given, st
+from hypothesis import given, strategies as st
 
 from repro.core import circuits, executor
 from repro.core.gates import restrict_to_reliable

@@ -8,7 +8,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hyp import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ops, ref
 from repro.kernels.common import gen_packed_bits, hash_u32, threshold_u32
@@ -83,8 +83,9 @@ def test_sng_words_pallas_equals_ref_all_shapes(n, b, bl):
 
 
 def test_sng_words_block_independent_and_equals_ref():
-    thr = threshold_u32(jax.random.uniform(KEY, (3, 100)))
-    seeds = lane_seeds(jnp.uint32(1), jnp.arange(3, dtype=jnp.uint32))
+    # 13 rows: a full and a ragged 8-row block; 17-element blocks: ragged too.
+    thr = threshold_u32(jax.random.uniform(KEY, (13, 100)))
+    seeds = lane_seeds(jnp.uint32(1), jnp.arange(13, dtype=jnp.uint32))
     a = sng_words(seeds, thr, 4, use_pallas=True, block_elems=256, interpret=True)
     b = sng_words(seeds, thr, 4, use_pallas=True, block_elems=17, interpret=True)
     assert (a == b).all()

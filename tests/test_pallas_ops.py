@@ -11,12 +11,14 @@ import itertools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.core import bitstream as bs
-from repro.core import circuits
+from repro.core import circuits, executor, obs
 from repro.core.plan import compile_plan
 from repro.core.streams import _gen_pi_streams
+from repro.kernels.common import InterpretModeWarning
 from repro.kernels.netlist_exec import run_combinational
 from repro.kernels.packed_logic import packed_logic
 from repro.kernels.plan_megakernel import combinational_megakernel
@@ -34,7 +36,10 @@ _OPS = {
     "mux": (3, lambda a, b, s: bs.mux(a, b, s)),
 }
 
-_SHAPES = [(8, 128), (5, 7), (17, 129), (1, 1), (3, 300)]
+# (40, 3) and (2, 300, 32) have fewer words than rows: the kernels view them
+# transposed (``common.lane_tiles``), which must change no bit.
+_SHAPES = [(8, 128), (5, 7), (17, 129), (1, 1), (3, 300), (40, 3),
+           (2, 300, 32)]
 
 
 def _words(i, shape):
@@ -59,6 +64,15 @@ def test_packed_logic_neg_masks_fold_in_kernel(op):
         got = packed_logic(op, *args, neg=neg, interpret=True)
         want = ref(*[~x if nb else x for x, nb in zip(args, neg)])
         assert (got == want).all(), (op, neg)
+
+
+def test_packed_logic_off_chip_interprets_with_a_warning():
+    # Left to choose its mode on a non-TPU backend, a kernel says it is
+    # interpreting instead of passing silently for a compiled run.
+    a, b = _words(20, (6, 9)), _words(21, (6, 9))
+    with pytest.warns(InterpretModeWarning, match="interpret mode"):
+        got = packed_logic("xor", a, b)
+    assert (got == bs.xor(a, b)).all()
 
 
 def test_packed_logic_validates_arity_and_neg():
@@ -109,7 +123,37 @@ def test_megakernel_falls_back_on_heterogeneous_pi_shapes():
     streams = dict(streams)
     k = next(iter(streams))
     streams[k] = jnp.broadcast_to(streams[k], (2,) + streams[k].shape)
+    before = _declines()
     assert combinational_megakernel(plan, streams, interpret=True) is None
+    assert _declines() == before + 1
+
+
+def _declines() -> int:
+    return obs.REGISTRY.snapshot()["counters"].get("megakernel.declined", 0)
+
+
+def test_mixed_shape_bank_counts_megakernel_decline():
+    # A merged bank whose members carry different batch shapes cannot lower
+    # into one kernel: the per-pass fallback still gives each member its
+    # standalone bits, and the decline is counted, not silent.  (The odd
+    # batch shape and BL keep this bank's jit trace private to this test.)
+    net = circuits.sc_multiply()
+    opts = executor.ExecOptions(backend="compiled_megakernel",
+                                bitstream_length=96, interpret=True)
+    reqs = [executor.ExecRequest(
+                net, {"a": np.full((13,), 0.3, np.float32),
+                      "b": np.full((13,), 0.7, np.float32)},
+                jax.random.key(1), opts),
+            executor.ExecRequest(net, {"a": 0.5, "b": 0.25},
+                                 jax.random.key(2), opts)]
+    before = _declines()
+    merged = executor.run(reqs)
+    assert _declines() > before
+    for req, got in zip(reqs, merged):
+        alone = executor.run(executor.ExecRequest(
+            net, req.values, req.key,
+            executor.ExecOptions(bitstream_length=96)))
+        assert (got["out"] == alone["out"]).all()
 
 
 def test_megakernel_rejects_fault_injection():

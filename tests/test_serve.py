@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hyp import HAVE_HYPOTHESIS, given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import arch, circuits, executor
 from repro.core.plan import (bucket_count, cache_info, compile_bank_template,
@@ -482,14 +482,11 @@ def test_fusion_disabled_without_fuse_mux():
 
 # --------------------------------- property test ----------------------------------
 
-if HAVE_HYPOTHESIS:
-    member_sets = st.lists(st.integers(min_value=0, max_value=len(POOL) - 1),
-                           min_size=1, max_size=6)
-    batches = st.sampled_from([None, (2,), (3,)])
-    key_modes = st.sampled_from(["batched", "legacy"])
-    rates = st.sampled_from([0.0, 0.1])
-else:                                          # placeholders; @given skips
-    member_sets = batches = key_modes = rates = None
+member_sets = st.lists(st.integers(min_value=0, max_value=len(POOL) - 1),
+                       min_size=1, max_size=6)
+batches = st.sampled_from([None, (2,), (3,)])
+key_modes = st.sampled_from(["batched", "legacy"])
+rates = st.sampled_from([0.0, 0.1])
 
 
 @settings(max_examples=20, deadline=None)

@@ -32,9 +32,9 @@ import time
 import jax
 import jax.numpy as jnp
 import pytest
-from _hyp import given, settings, st
+from hypothesis import given, settings, strategies as st
 
-from repro.core import circuits, executor
+from repro.core import circuits, dispatch, executor
 from repro.serve import (BankServer, DeadlineExceeded, RequestShed,
                          ServerClosed, circuit_request)
 
@@ -214,6 +214,89 @@ def test_no_retry_budget_fails_fast():
         with pytest.raises(RuntimeError, match="injected"):
             t.result()
         assert srv.stats()["retries"] == 0
+
+
+def test_program_error_fails_ticket_without_retry_or_device_charge():
+    # A batch whose program cannot be built — here a missing PI value, a
+    # trace-time KeyError, as a kernel the chip's compiler refuses would be
+    # at compile time — fails its tickets with that very error.  Retrying
+    # would rebuild the same program, and the device did nothing wrong.
+    bad = circuit_request(MUL, {"a": 0.3}, jax.random.key(5), BL)
+    with BankServer(max_slots=1, max_retries=3, quarantine_after=1) as srv:
+        t = srv.submit(bad)
+        with pytest.raises(KeyError) as exc_info:
+            t.result()
+        assert any("[BankServer]" in n for n in exc_info.value.__notes__)
+        st_ = srv.stats()
+        assert st_["failed_requests"] == 1
+        assert st_["retries"] == st_["quarantines"] == 0
+        assert not any(srv._consec_failures.values())
+        # The server stays serviceable.
+        assert tree_eq(srv.submit(req(1)).result(), ref(req(1)))
+
+
+class FlakyDispatch:
+    """Stand-in for the jitted bank program whose *dispatch* raises a device
+    error — out of HBM, as a chip under memory pressure reports it — on
+    ``device`` (None: any) ``failures`` times; compiling it never fails."""
+
+    def __init__(self, fn, device=None, failures: int = 1):
+        self.fn, self.device, self.failures = fn, device, failures
+
+    def trace(self, *args, **kw):
+        return self.fn.trace(*args, **kw)
+
+    def __call__(self, *args, **kw):
+        keys = args[2]
+        if self.failures and (self.device is None or
+                              self.device in keys.devices()):
+            self.failures -= 1
+            raise jax.errors.JaxRuntimeError(
+                "RESOURCE_EXHAUSTED: out of memory allocating the batch")
+        return self.fn(*args, **kw)
+
+
+@pytest.mark.parametrize("max_retries", [0, 1])
+def test_dispatch_device_error_is_charged_to_device(monkeypatch, max_retries):
+    # An error raised while the batch is enqueued, with a program that
+    # compiles fine, is the device's: it is retried and counts towards the
+    # device's quarantine.
+    monkeypatch.setattr(dispatch, "_execute_bank",
+                        FlakyDispatch(dispatch._execute_bank))
+    d0 = jax.devices()[0]
+    with BankServer(max_slots=1, max_retries=max_retries,
+                    retry_backoff_s=0.001, quarantine_after=100) as srv:
+        t = srv.submit(req(0))
+        if max_retries:
+            assert tree_eq(t.result(), ref(req(0)))
+        else:
+            with pytest.raises(jax.errors.JaxRuntimeError,
+                               match="RESOURCE_EXHAUSTED"):
+                t.result()
+            assert srv._consec_failures[d0] == 1
+        st_ = srv.stats()
+        assert st_["retries"] == max_retries
+        assert st_["failed_requests"] == 1 - max_retries
+
+
+@needs_multidevice
+def test_dispatch_device_error_moves_work_to_healthy_device(monkeypatch):
+    devices = jax.devices()
+    victim = devices[1]
+    monkeypatch.setattr(dispatch, "_execute_bank", FlakyDispatch(
+        dispatch._execute_bank, device=victim, failures=10 ** 6))
+    with BankServer(max_slots=1, devices=devices, placement="round_robin",
+                    max_retries=2, retry_backoff_s=0.001,
+                    quarantine_after=1, quarantine_s=60.0) as srv:
+        reqs = [req(i) for i in range(2 * len(devices))]
+        tickets = [srv.submit(r) for r in reqs]
+        srv.flush()
+        for r, t in zip(reqs, tickets):
+            assert tree_eq(t.result(), ref(r))
+        st_ = srv.stats()
+        assert st_["failed_requests"] == 0 and st_["retries"] >= 1
+        assert [d["device"] for d in st_["devices"] if d["quarantined"]] \
+            == [str(victim)]
 
 
 @given(st.integers(min_value=1, max_value=3),

@@ -3,7 +3,7 @@ sequential recurrence — the ground truth the chunked algebra must equal."""
 import jax
 import jax.numpy as jnp
 import numpy as np
-from _hyp import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels.ref_wkv import wkv_ref
 from repro.kernels.wkv import wkv
