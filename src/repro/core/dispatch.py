@@ -85,6 +85,10 @@ def _execute_compiled(plan: ExecutionPlan, values: dict[str, jax.Array],
     (``kernels/plan_megakernel``); ``interpret`` reaches every Pallas kernel
     of the program (None: compiled on a TPU, see
     ``kernels.common.resolve_interpret``).
+
+    Each layer's operations carry a name scope that the device trace keeps:
+    ``sc.sng`` (stream generation), ``sc.faults``, ``sc.passes`` or
+    ``sc.scan`` (sequential plans), and ``sc.decode``.
     """
     from ..kernels import netlist_exec
 
@@ -117,12 +121,13 @@ def _execute_compiled(plan: ExecutionPlan, values: dict[str, jax.Array],
 
     gate_fkeys = None
     if inject:
-        fk = flip_key if flip_key is not None else jax.random.key(0)
-        fkeys = jax.random.split(fk, len(streams) + plan.n_gates)
-        for i, name in enumerate(sorted(streams)):
-            streams[name] = _faults.apply_faults(fkeys[i], streams[name],
-                                                 bitflip_rate, fault_model)
-        gate_fkeys = fkeys[len(streams):]
+        with jax.named_scope("sc.faults"):
+            fk = flip_key if flip_key is not None else jax.random.key(0)
+            fkeys = jax.random.split(fk, len(streams) + plan.n_gates)
+            for i, name in enumerate(sorted(streams)):
+                streams[name] = _faults.apply_faults(fkeys[i], streams[name],
+                                                     bitflip_rate, fault_model)
+            gate_fkeys = fkeys[len(streams):]
 
     if not plan.is_sequential:
         env = dict(streams)
@@ -140,14 +145,21 @@ def _execute_compiled(plan: ExecutionPlan, values: dict[str, jax.Array],
             batch_shape=batch_shape,
             megakernel=megakernel, interpret=interpret)
         if gate_fkeys is not None:
-            for i, o in enumerate(sorted(packed_outs)):
-                packed_outs[o] = _faults.apply_faults(gate_fkeys[i],
-                                                      packed_outs[o],
-                                                      bitflip_rate, fault_model)
-    if decode:
+            with jax.named_scope("sc.faults"):
+                for i, o in enumerate(sorted(packed_outs)):
+                    packed_outs[o] = _faults.apply_faults(
+                        gate_fkeys[i], packed_outs[o], bitflip_rate,
+                        fault_model)
+    return _decode(packed_outs, bitstream_length) if decode else packed_outs
+
+
+def _decode(packed_outs: dict[str, jax.Array],
+            bitstream_length: int) -> dict[str, jax.Array]:
+    """The StoB popcount decode of each output stream, under the name scope
+    ``sc.decode``."""
+    with jax.named_scope("sc.decode"):
         return {o: bs.to_value(w, bitstream_length)
                 for o, w in packed_outs.items()}
-    return packed_outs
 
 
 def _execute_chunked(plan: ExecutionPlan, values, key, bitstream_length: int,
@@ -198,10 +210,7 @@ def _execute_chunked(plan: ExecutionPlan, values, key, bitstream_length: int,
     for o, y in zip(plan.outputs, ys):      # y: (n_chunks, *batch, word_chunk)
         y = jnp.moveaxis(y, 0, -2)
         packed_outs[o] = y.reshape(y.shape[:-2] + (w,))
-    if decode:
-        return {o: bs.to_value(v, bitstream_length)
-                for o, v in packed_outs.items()}
-    return packed_outs
+    return _decode(packed_outs, bitstream_length) if decode else packed_outs
 
 
 def _binary_env(pis, operand_bits: dict[str, jax.Array]) -> dict[str, jax.Array]:
@@ -307,7 +316,7 @@ def _dispatch(net: Netlist, values, key, bitstream_length: int,
             outs = {k: bs.to_value(v, bitstream_length) for k, v in outs.items()}
         return outs
     plan = _plan_for(net, bitflip_rate, fault_model)
-    values = {k: jnp.asarray(v, jnp.float32) for k, v in values.items()}
+    values = _put_values(values)
     with obs.span("exec.dispatch", plan=plan.name,
                   bitstream_length=bitstream_length):
         return _execute_compiled(plan, values, key, flip_key, bitstream_length,
@@ -460,13 +469,14 @@ def _execute_bank_impl(bank: BankPlan, values_seq, keys, flip_keys,
         masked = active is not None and not active[i]
         tail = None
         if inject and len(streams) + plan.n_gates > 0:
-            fkeys = jax.random.split(flip_keys[i], len(streams) + plan.n_gates)
-            if not masked:
-                for j, nm in enumerate(sorted(streams)):
-                    streams[nm] = _faults.apply_faults(fkeys[j], streams[nm],
-                                                       bitflip_rate,
-                                                       fault_model)
-            tail = fkeys[len(streams):]
+            with jax.named_scope("sc.faults"):
+                fkeys = jax.random.split(flip_keys[i],
+                                         len(streams) + plan.n_gates)
+                if not masked:
+                    for j, nm in enumerate(sorted(streams)):
+                        streams[nm] = _faults.apply_faults(
+                            fkeys[j], streams[nm], bitflip_rate, fault_model)
+                tail = fkeys[len(streams):]
         native_batch[i] = (next(iter(streams.values())).shape[:-1]
                            if streams else ())
         target = seq_words if plan.is_sequential else comb_env
@@ -506,13 +516,13 @@ def _execute_bank_impl(bank: BankPlan, values_seq, keys, flip_keys,
                  for o in bank.members[i].outputs}
             if inject:
                 tail = seq_out_fkeys[i]
-                for j, o in enumerate(sorted(m)):
-                    m[o] = _faults.apply_faults(tail[j], m[o], bitflip_rate,
-                                                fault_model)
+                with jax.named_scope("sc.faults"):
+                    for j, o in enumerate(sorted(m)):
+                        m[o] = _faults.apply_faults(tail[j], m[o],
+                                                    bitflip_rate, fault_model)
             outs[i] = m
     if decode:
-        outs = [m if m is None else
-                {o: bs.to_value(w, bitstream_length) for o, w in m.items()}
+        outs = [m if m is None else _decode(m, bitstream_length)
                 for m in outs]
     return tuple(outs)
 
@@ -551,6 +561,18 @@ def _as_f32(v) -> jax.Array:
     if _is_jax_array(v) and v.dtype == jnp.float32:
         return v
     return jnp.asarray(v, jnp.float32)
+
+
+def _put_values(values: dict) -> dict[str, jax.Array]:
+    """Each PI value as a float32 device array, one host-to-device transfer
+    per host array, under the host span ``exec.put_values`` with the
+    counters ``arrays`` and ``bytes``."""
+    with obs.span("exec.put_values") as sp:
+        out = {k: _as_f32(v) for k, v in values.items()}
+        if sp is not obs.NULL_SPAN:
+            sp.set("arrays", len(out))
+            sp.set("bytes", sum(a.nbytes for a in out.values()))
+    return out
 
 
 def _is_host_scalar(v) -> bool:

@@ -10,16 +10,17 @@ them, and the historic ``execute*`` functions as thin shims that build
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import warnings
 from typing import Any
 
 import jax
 
 from . import obs
-from .dispatch import (_as_f32, _check_fault_args, _check_modes, _dispatch,
+from .dispatch import (_check_fault_args, _check_modes, _dispatch,
                        _dispatch_binary, _dispatch_many, _execute_compiled,
-                       _normalize_batch_shapes, _normalize_keys, _stack_keys,
-                       execute_bank, precompile_bank)
+                       _normalize_batch_shapes, _normalize_keys, _put_values,
+                       _stack_keys, execute_bank, precompile_bank)
 from .faults import FaultModel
 from .gates import Netlist
 from .plan import BankPlan, ExecutionPlan
@@ -315,6 +316,9 @@ def execute_value_many(nets, values_seq, /, *args, **kwargs) -> list:
 
 # ------------------------------ run() entry point ---------------------------------
 
+#: Ids of traced ``run()`` calls, tagging the spans each one causes.
+_RUN_IDS = itertools.count(1)
+
 _SHARED_OPTION_FIELDS = ("backend", "key_mode", "bitstream_length",
                          "bitflip_rate", "decode", "binary", "fault_model",
                          "word_chunk", "interpret")
@@ -357,7 +361,7 @@ def _run_one(req: ExecRequest, device=None,
                                         flip_key)
         batch_shape = (tuple(o.batch_shape)
                        if o.batch_shape is not None else None)
-        values = {k: _as_f32(v) for k, v in values.items()}
+        values = _put_values(values)
         with obs.span("exec.dispatch", plan=req.net.name,
                       bitstream_length=o.bitstream_length):
             return _execute_compiled(
@@ -512,6 +516,10 @@ def run(request_or_requests, *, template: BankPlan | None = None,
     depend only on its own key (and ``key_mode``), never on which batch,
     slot, or device it executed in.
 
+    A traced call (an ``options.trace``, or a trace already current) takes
+    the next id of a process-wide counter, and every span it causes
+    carries it as ``run`` (``obs.tracing``).
+
     Example::
 
         import jax
@@ -534,9 +542,11 @@ def run(request_or_requests, *, template: BankPlan | None = None,
         else next((r.options.trace for r in reqs
                    if r is not None and r.options.trace is not None), None)
     if tr is None:
-        return _run_any(reqs, single, template, active, device, donate,
-                        options)
-    with obs.tracing(tr):
+        tr = obs.current_trace()
+        if tr is None:
+            return _run_any(reqs, single, template, active, device, donate,
+                            options)
+    with obs.tracing(tr, run=next(_RUN_IDS)):
         return _run_any(reqs, single, template, active, device, donate,
                         options)
 

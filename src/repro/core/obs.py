@@ -19,7 +19,10 @@ Three pieces:
   to emit a request's queued/staged/inflight phases at reap time), and
   ``trace.event(...)`` records instant events (retry, quarantine, shed).
   Exporters: ``to_chrome_json()`` (load in chrome://tracing or Perfetto)
-  and ``summary()`` (flat per-span-name totals).
+  and ``summary()`` (flat per-span-name totals).  Each live span is also
+  entered as a ``jax.profiler.TraceAnnotation`` of the same name, so that
+  a running ``jax.profiler`` session records it on the device trace's
+  clock (and nothing is recorded when none runs).
 
 * ``MetricsRegistry`` — named counters / gauges / histograms behind one
   lock.  Every ``Trace`` owns one (``trace.metrics``); a process-wide
@@ -30,7 +33,10 @@ Three pieces:
   fallback (what ``REPRO_TRACE=1`` does at import), and ``span(...)`` /
   ``event(...)`` module-level helpers no-op cheaply when neither is set —
   the disabled path is one contextvar read, so instrumented hot paths cost
-  nothing measurable when tracing is off.
+  nothing measurable when tracing is off.  ``tracing(trace, run=n)`` also
+  names the ``run()`` call that caused the block's spans: each span then
+  carries ``run=n``, as an attribute and as the annotation's argument
+  (``name#run=n#`` in the profiler trace), so one frame's spans share it.
 
 Example::
 
@@ -233,6 +239,19 @@ class MetricsRegistry:
 REGISTRY = MetricsRegistry()
 
 
+#: ``jax.profiler.TraceAnnotation``, looked up on the first live span so
+#: that importing this module imports no jax.
+_Annotation: Any = None
+
+
+def _annotation_class():
+    global _Annotation
+    if _Annotation is None:
+        from jax.profiler import TraceAnnotation
+        _Annotation = TraceAnnotation
+    return _Annotation
+
+
 class Trace:
     """An in-memory collection of spans + instant events + metrics.
 
@@ -261,13 +280,22 @@ class Trace:
 
     @contextmanager
     def span(self, name: str, **attrs: Any) -> Iterator[Span]:
-        """Open a live nested span; closed (and recorded) on exit."""
+        """Open a live nested span; closed (and recorded) on exit.
+
+        The span is also a profiler annotation of the same name, with the
+        current ``run`` id as its argument when one is set."""
+        run = _run.get()
+        if run is not None:
+            attrs["run"] = run
+        cls = _annotation_class()
+        ann = cls(name) if run is None else cls(name, run=run)
         st = self._stack()
         sp = Span(name, time.perf_counter(), None, threading.get_ident(),
                   st[-1] if st else None, attrs)
         st.append(sp)
         try:
-            yield sp
+            with ann:
+                yield sp
         finally:
             st.pop()
             sp.t1 = time.perf_counter()
@@ -393,6 +421,8 @@ def _jsonable(attrs: dict) -> dict:
 _current: "contextvars.ContextVar[Trace | None]" = contextvars.ContextVar(
     "repro_obs_trace", default=None)
 _installed: "Trace | None" = None
+_run: "contextvars.ContextVar[int | None]" = contextvars.ContextVar(
+    "repro_obs_run", default=None)
 
 
 def current_trace() -> "Trace | None":
@@ -414,12 +444,16 @@ def install(trace: "Trace | None") -> "Trace | None":
 
 
 @contextmanager
-def tracing(trace: Trace) -> Iterator[Trace]:
-    """Make ``trace`` the current trace for the dynamic extent of a block."""
+def tracing(trace: Trace, run: "int | None" = None) -> Iterator[Trace]:
+    """Make ``trace`` the current trace for the dynamic extent of a block;
+    ``run`` (the id of the ``run()`` call behind the block) tags every span
+    opened in it."""
     token = _current.set(trace)
+    run_token = _run.set(run)
     try:
         yield trace
     finally:
+        _run.reset(run_token)
         _current.reset(token)
 
 

@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 
 from . import bitstream as bs
-from . import obs
 from .gates import PIKind
 from .plan import BankPlan, StreamTable, build_stream_table
 
@@ -83,11 +82,9 @@ def _gen_pi_streams(pis, values: dict[str, jax.Array], key: jax.Array,
     instead of holding full-length streams live.  The legacy threefry
     discipline draws all words in one monolithic call and cannot window.
     ``interpret`` forwards to the Pallas SNG kernel (``use_pallas``).
+    Its operations carry the name scope ``sc.sng`` in the device trace.
     """
-    # Under the compiled backends this body runs at jit-trace time, so the
-    # span measures lowering cost (a cache-miss-only host cost), not
-    # steady-state runtime; on the reference backend it runs eagerly.
-    with obs.span("streams.gen_pi", key_mode=key_mode, trace_time=True):
+    with jax.named_scope("sc.sng"):
         return _gen_pi_streams_impl(pis, values, key, bitstream_length,
                                     key_mode, batch_shape, use_pallas, table,
                                     word_window, interpret)
@@ -168,11 +165,9 @@ def _gen_bank_streams(bank: BankPlan, values_seq, keys, bitstream_length: int,
     logic passes well-formed.  Active members' streams are untouched by the
     masking, so padded execution stays bit-identical per bound slot.
     ``interpret`` forwards to the Pallas SNG kernel (``use_pallas``).
+    Its operations carry the name scope ``sc.sng``, as in ``_gen_pi_streams``.
     """
-    # Like _gen_pi_streams: under jit this span measures trace/lowering
-    # cost (cache misses only), not per-call runtime.
-    with obs.span("streams.gen_bank", bank=bank.name, key_mode=key_mode,
-                  trace_time=True):
+    with jax.named_scope("sc.sng"):
         return _gen_bank_streams_impl(bank, values_seq, keys,
                                       bitstream_length, key_mode, use_pallas,
                                       batch_shapes, active, interpret)

@@ -91,44 +91,49 @@ def run_combinational(plan: ExecutionPlan, env: dict[str, jax.Array],
     pass, every node in ``cop.free_after`` (computed by the compiler's
     liveness stage) is dropped from ``env``, bounding eager/interpret
     residency at ``plan.max_live`` streams instead of one per node.
+
+    Every operation of the passes carries the name scope ``sc.passes``, so
+    the device trace names this layer.
     """
-    inject = gate_fkeys is not None and \
-        _faults.injecting(bitflip_rate, fault_model)
-    if inject and plan.fused:
-        raise ValueError("per-gate fault injection requires an unfused plan")
-    if megakernel:
-        if inject:
-            raise ValueError(
-                "megakernel execution cannot inject per-gate faults: "
-                "intermediate pass outputs never leave the kernel")
-        from .plan_megakernel import combinational_megakernel
-        res = combinational_megakernel(plan, env, interpret=interpret)
-        if res is not None:
-            env.update(res)
-            return env
-    for level in plan.levels:
-        for cop in level:
-            k = cop.n_batched
-            if k == 1:
-                ins = [env[names[0]] for names in cop.inputs]
-                outs = [_apply_pass(cop.op, ins, use_pallas, cop.neg,
-                                    interpret)]
-            else:
-                outs = _batched_pass(cop, env, use_pallas, interpret)
+    with jax.named_scope("sc.passes"):
+        inject = gate_fkeys is not None and \
+            _faults.injecting(bitflip_rate, fault_model)
+        if inject and plan.fused:
+            raise ValueError("per-gate fault injection requires an unfused plan")
+        if megakernel:
             if inject:
-                outs = [_faults.apply_faults(gate_fkeys[gid], o,
-                                             bitflip_rate, fault_model)
-                        for gid, o in zip(cop.gids, outs)]
-            for name, o in zip(cop.outputs, outs):
-                env[name] = o
-            for name in cop.free_after:
-                env.pop(name, None)
-    # Re-expose nodes elided by BUFF elision / CSE: each aliases the surviving
-    # node computing the identical stream, so outputs and state drivers that
-    # were deduplicated away stay readable (zero extra passes).
-    for src, dst in plan.aliases:
-        env[src] = env[dst]
-    return env
+                raise ValueError(
+                    "megakernel execution cannot inject per-gate faults: "
+                    "intermediate pass outputs never leave the kernel")
+            from .plan_megakernel import combinational_megakernel
+            res = combinational_megakernel(plan, env, interpret=interpret)
+            if res is not None:
+                env.update(res)
+                return env
+        for level in plan.levels:
+            for cop in level:
+                k = cop.n_batched
+                if k == 1:
+                    ins = [env[names[0]] for names in cop.inputs]
+                    outs = [_apply_pass(cop.op, ins, use_pallas, cop.neg,
+                                        interpret)]
+                else:
+                    outs = _batched_pass(cop, env, use_pallas, interpret)
+                if inject:
+                    outs = [_faults.apply_faults(gate_fkeys[gid], o,
+                                                 bitflip_rate, fault_model)
+                            for gid, o in zip(cop.gids, outs)]
+                for name, o in zip(cop.outputs, outs):
+                    env[name] = o
+                for name in cop.free_after:
+                    env.pop(name, None)
+        # Re-expose nodes elided by BUFF elision / CSE: each aliases the
+        # surviving node computing the identical stream, so outputs and state
+        # drivers that were deduplicated away stay readable (zero extra
+        # passes).
+        for src, dst in plan.aliases:
+            env[src] = env[dst]
+        return env
 
 
 def _batched_pass(cop, env: dict[str, jax.Array], use_pallas: bool,
@@ -190,52 +195,54 @@ def run_sequential(plan: ExecutionPlan, pi_words: dict[str, jax.Array],
     state and outputs).
 
     ``megakernel``/``interpret`` forward to the per-bit combinational body.
+    Its operations carry the name scope ``sc.scan`` in the device trace.
     """
-    names = plan.stream_pi_names()
-    if names:
-        shapes = {pi_words[n].shape for n in names}
-        if len(shapes) > 1:
-            common = jnp.broadcast_shapes(*shapes)
-            stacked = jnp.stack([jnp.broadcast_to(pi_words[n], common)
-                                 for n in names])              # (P, ..., W)
+    with jax.named_scope("sc.scan"):
+        names = plan.stream_pi_names()
+        if names:
+            shapes = {pi_words[n].shape for n in names}
+            if len(shapes) > 1:
+                common = jnp.broadcast_shapes(*shapes)
+                stacked = jnp.stack([jnp.broadcast_to(pi_words[n], common)
+                                     for n in names])              # (P, ..., W)
+            else:
+                stacked = jnp.stack([pi_words[n] for n in names])  # (P, ..., W)
+            batch = stacked.shape[1:-1]
+            xs = jnp.moveaxis(stacked, -1, 0)                      # (W, P, ...)
         else:
-            stacked = jnp.stack([pi_words[n] for n in names])  # (P, ..., W)
-        batch = stacked.shape[1:-1]
-        xs = jnp.moveaxis(stacked, -1, 0)                      # (W, P, ...)
-    else:
-        if n_words is None:
-            raise ValueError(
-                f"plan {plan.name} has no stream PIs; pass n_words "
-                "(= bitstream_length // 32) to size the scan")
-        batch = tuple(batch_shape) if batch_shape else ()
-        xs = jnp.zeros((n_words, 0), jnp.uint32)               # (W, 0)
+            if n_words is None:
+                raise ValueError(
+                    f"plan {plan.name} has no stream PIs; pass n_words "
+                    "(= bitstream_length // 32) to size the scan")
+            batch = tuple(batch_shape) if batch_shape else ()
+            xs = jnp.zeros((n_words, 0), jnp.uint32)               # (W, 0)
 
-    state0 = tuple(jnp.full(batch, jnp.uint32(round(init)))
-                   for init in plan.state_inits)
-    n_out = len(plan.outputs)
+        state0 = tuple(jnp.full(batch, jnp.uint32(round(init)))
+                       for init in plan.state_inits)
+        n_out = len(plan.outputs)
 
-    def word_step(state, word):                                # word: (P, ...)
-        zeros = tuple(jnp.zeros(batch, jnp.uint32) for _ in range(n_out))
+        def word_step(state, word):                                # word: (P, ...)
+            zeros = tuple(jnp.zeros(batch, jnp.uint32) for _ in range(n_out))
 
-        def bit_step(i, carry):
-            state, out_words = carry
-            sh = jnp.uint32(i)
-            env = {n: (word[j] >> sh) & jnp.uint32(1)
-                   for j, n in enumerate(names)}
-            for s_name, s_val in zip(plan.state_pis, state):
-                env[s_name] = s_val
-            run_combinational(plan, env, use_pallas=use_pallas,
-                              megakernel=megakernel, interpret=interpret)
-            new_state = tuple(env[d] for d in plan.state_drivers)
-            # Mask to bit 0 before packing: inverting gates (~x) carry
-            # garbage in bits 1..31 of the per-bit env values.
-            out_words = tuple(w | ((env[o] & jnp.uint32(1)) << sh)
-                              for w, o in zip(out_words, plan.outputs))
-            return new_state, out_words
+            def bit_step(i, carry):
+                state, out_words = carry
+                sh = jnp.uint32(i)
+                env = {n: (word[j] >> sh) & jnp.uint32(1)
+                       for j, n in enumerate(names)}
+                for s_name, s_val in zip(plan.state_pis, state):
+                    env[s_name] = s_val
+                run_combinational(plan, env, use_pallas=use_pallas,
+                                  megakernel=megakernel, interpret=interpret)
+                new_state = tuple(env[d] for d in plan.state_drivers)
+                # Mask to bit 0 before packing: inverting gates (~x) carry
+                # garbage in bits 1..31 of the per-bit env values.
+                out_words = tuple(w | ((env[o] & jnp.uint32(1)) << sh)
+                                  for w, o in zip(out_words, plan.outputs))
+                return new_state, out_words
 
-        state, out_words = jax.lax.fori_loop(0, bs.WORD_BITS, bit_step,
-                                             (state, zeros))
-        return state, out_words
+            state, out_words = jax.lax.fori_loop(0, bs.WORD_BITS, bit_step,
+                                                 (state, zeros))
+            return state, out_words
 
-    _, ys = jax.lax.scan(word_step, state0, xs)                # each: (W, ...)
-    return {o: jnp.moveaxis(y, 0, -1) for o, y in zip(plan.outputs, ys)}
+        _, ys = jax.lax.scan(word_step, state0, xs)                # each: (W, ...)
+        return {o: jnp.moveaxis(y, 0, -1) for o, y in zip(plan.outputs, ys)}

@@ -9,16 +9,23 @@ Covers the tentpole contracts of the observability PR:
 * the serving engine's counters match a known request trace exactly, and
   its per-request phase spans partition the root request span;
 * tracing is observability only: enabling it changes NO bits, under both
-  key modes, through the executor and the server.
+  key modes, through the executor and the server;
+* each live span is also a profiler annotation carrying its ``run()`` id,
+  and each layer of the compiled program carries an ``sc.*`` name scope
+  that the compiled HLO keeps.
 """
 import json
+import re
 import threading
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
-from repro.core import circuits, executor, obs
+from repro.core import apps, circuits, dispatch, executor, obs
+from repro.core.appnet import APP_NETLISTS
+from repro.core.plan import compile_plan
 from repro.serve import BankServer, SCRequest, circuit_request
 
 
@@ -217,3 +224,109 @@ def test_tracing_changes_no_bits_server(key_mode):
         assert b.keys() == t.keys()
         for k in b:
             assert bool(jnp.array_equal(b[k], t[k]))
+
+
+# --------------------------- profiler annotations ---------------------------
+
+@pytest.fixture
+def annotations(monkeypatch):
+    """A recording stand-in for ``jax.profiler.TraceAnnotation``: the
+    ``(name, kwargs)`` of every annotation entered."""
+    entered = []
+
+    class Recorder:
+        def __init__(self, name, **kwargs):
+            self.args = (name, kwargs)
+
+        def __enter__(self):
+            entered.append(self.args)
+            return self
+
+        def __exit__(self, *exc):
+            return None
+
+    monkeypatch.setattr(obs, "_Annotation", Recorder)
+    return entered
+
+
+def _lit_frame(pixels=4):
+    a = np.random.default_rng(0).uniform(0.05, 0.95, (pixels, 81))
+    return apps.appnet_inputs("lit", a=a.astype(np.float32))
+
+
+def test_spans_enter_annotations_with_their_run_id(annotations):
+    tr = obs.Trace("ann")
+    with obs.tracing(tr, run=7):
+        with obs.span("exec.dispatch", plan="p"):
+            pass
+    with tr.span("outside-a-run"):
+        pass
+    assert annotations == [("exec.dispatch", {"run": 7}),
+                           ("outside-a-run", {})]
+    assert tr.spans()[0].attrs == {"plan": "p", "run": 7}
+
+    # Through run(): each call takes its own id, and the spans of one call
+    # share it.
+    del annotations[:]
+    opts = executor.ExecOptions(bitstream_length=64, decode=True)
+    req = executor.ExecRequest(circuits.sc_multiply(),
+                               {"a": np.full(4, 0.3, np.float32),
+                                "b": np.full(4, 0.6, np.float32)},
+                               jax.random.key(1), opts)
+    with obs.tracing(tr):
+        executor.run(req)
+        executor.run(req)
+    names = [n for n, _ in annotations]
+    assert names.count("exec.put_values") == 2
+    assert names.count("exec.dispatch") == 2
+    runs = [kw["run"] for n, kw in annotations if n.startswith("exec.")]
+    assert runs[0] == runs[1] and runs[2] == runs[3] and runs[0] != runs[2]
+
+
+def test_no_annotation_without_a_trace(annotations):
+    assert obs.current_trace() is None
+    with obs.span("exec.dispatch"):
+        pass
+    executor.run(executor.ExecRequest(
+        circuits.sc_multiply(), {"a": 0.3, "b": 0.6}, jax.random.key(1),
+        executor.ExecOptions(bitstream_length=64, decode=True)))
+    assert annotations == []
+
+
+def _scopes_in(hlo_text: str) -> set:
+    return {m for name in re.findall(r'op_name="([^"]*)"', hlo_text)
+            for m in re.findall(r"\bsc\.[a-z]+", name)}
+
+
+def test_compiled_hlo_names_each_layer():
+    """The compiled program keeps the layers' name scopes in its ops'
+    ``op_name`` metadata, which the profiler reports as ``tf_op``."""
+    plan = compile_plan(APP_NETLISTS["lit"]())
+    values = {k: jnp.asarray(v) for k, v in _lit_frame().items()}
+    text = dispatch._execute_compiled.lower(
+        plan, values, jax.random.key(0), None, 256, 0.0, False,
+        decode=True).compile().as_text()
+    assert {"sc.sng", "sc.passes", "sc.decode"} <= _scopes_in(text)
+
+    seq = compile_plan(circuits.sc_scaled_div())
+    assert seq.is_sequential
+    text = dispatch._execute_compiled.lower(
+        seq, {"a": jnp.float32(0.2), "b": jnp.float32(0.6)},
+        jax.random.key(0), jax.random.key(1), 64, 0.01, False,
+        decode=True).compile().as_text()
+    assert {"sc.sng", "sc.scan", "sc.faults", "sc.decode"} <= \
+        _scopes_in(text)
+
+
+def test_put_values_counts_a_lit_frame():
+    tr = obs.Trace("lit")
+    pixels = 4
+    executor.run(executor.ExecRequest(
+        APP_NETLISTS["lit"](), _lit_frame(pixels), jax.random.key(2),
+        executor.ExecOptions(bitstream_length=64, decode=True, trace=tr)))
+    (put,) = [s for s in tr.spans() if s.name == "exec.put_values"]
+    assert put.attrs["arrays"] == 81
+    assert put.attrs["bytes"] == 81 * pixels * 4
+    (disp,) = [s for s in tr.spans() if s.name == "exec.dispatch"]
+    assert put.attrs["run"] == disp.attrs["run"]
+    assert put.t1 <= disp.t0
