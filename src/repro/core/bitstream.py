@@ -101,8 +101,9 @@ def generate_correlated(key: jax.Array, ps: tuple[jax.Array, ...] | list[jax.Arr
 # threefry call per stream.  Rows with equal key-lane index share their
 # uniforms, so correlation groups ride through the same pass.  This is the
 # ``key_mode="batched"`` discipline (executor.py): streams differ bit-wise
-# from the legacy per-PI threefry splits but are statistically equivalent,
-# and the jnp fallback is bit-identical to the Pallas kernel.
+# from the legacy per-PI threefry splits but are statistically equivalent.
+# The jnp path (``kernels.sng.sng_words_jnp``, the default) and the Pallas
+# kernel are bit-identical, both tested against ``ref.sng_words_ref``.
 
 def stream_row_seeds(key: jax.Array, lanes) -> jax.Array:
     """Mixed per-row seeds for a stream table: row i <- hash(key seed, lane_i).
@@ -125,9 +126,10 @@ def generate_batch_seeded(row_seeds: jax.Array, ps: jax.Array,
 
     Thresholds and packs by compare-and-accumulate over the 32 lane shifts —
     the (..., W, 32) unpacked uniform tensor of ``generate`` is never
-    materialized.  ``use_pallas`` routes through the fused Pallas SNG kernel
-    (kernels/sng.py), bit-identical to the jnp fallback; ``interpret``
-    forwards to it (None: compiled on a TPU).
+    materialized.  The default is the jnp path (``kernels.sng.sng_words_jnp``);
+    ``use_pallas`` routes through the fused Pallas SNG kernel instead, and both
+    are bit-identical to the oracle ``ref.sng_words_ref``; ``interpret``
+    forwards to the kernel (None: compiled on a TPU).
 
     ``word_window=(start, n)`` generates only words ``[start, start + n)`` of
     the ``bitstream_length``-long streams — bit-identical to slicing a

@@ -16,6 +16,9 @@ import jax
 import jax.numpy as jnp
 
 WORD_BITS = 32
+# Murmur3 finalizer multipliers (``hash_u32``).
+MURMUR_K1 = 0x85EBCA6B
+MURMUR_K2 = 0xC2B2AE35
 
 
 class InterpretModeWarning(UserWarning):
@@ -71,9 +74,9 @@ def hash_u32(x: jax.Array) -> jax.Array:
     """Murmur3 finalizer: uint32 -> well-mixed uint32."""
     x = x.astype(jnp.uint32)
     x = x ^ (x >> 16)
-    x = x * jnp.uint32(0x85EBCA6B)
+    x = x * jnp.uint32(MURMUR_K1)
     x = x ^ (x >> 13)
-    x = x * jnp.uint32(0xC2B2AE35)
+    x = x * jnp.uint32(MURMUR_K2)
     x = x ^ (x >> 16)
     return x
 

@@ -42,8 +42,8 @@ def sng_table(row_seeds: jax.Array, thr: jax.Array, bitstream_length: int = 256,
     if bitstream_length % 32 != 0:
         raise ValueError(f"bitstream length {bitstream_length} must be a "
                          "multiple of 32")
-    # sng_words routes to the ref oracle itself when use_pallas=False and
-    # resolves interpret mode (common.resolve_interpret) otherwise.
+    # sng_words routes to its jnp path (sng_words_jnp) when use_pallas=False
+    # and resolves interpret mode (common.resolve_interpret) otherwise.
     return _sng_words(row_seeds, thr, bitstream_length // 32,
                       use_pallas=use_pallas)
 

@@ -18,8 +18,9 @@ The kernel packs by compare-and-accumulate over the 32 lane shifts: the
 (…, W, 32) unpacked bit tensor is never materialized (32x less live memory
 than the threshold-then-pack formulation).  Counters derive from global
 (element, bit) indices, so output is tiling-independent and bit-identical to
-``ref.sng_words_ref`` — the jnp fallback the executor uses by default
-(``use_pallas`` opts into the kernel).
+``sng_words_jnp``, the jnp path the executor uses by default (``use_pallas``
+opts into the kernel).  ``ref.sng_words_ref`` is the oracle both are tested
+against.
 """
 from __future__ import annotations
 
@@ -29,15 +30,63 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from . import ref
-from .common import (WORD_BITS, hash_u32, mix_seed, resolve_interpret,
-                     threshold_u32)
+from .common import (MURMUR_K1, MURMUR_K2, WORD_BITS, hash_u32, mix_seed,
+                     resolve_interpret, threshold_u32)
 
 
 def lane_seeds(seed: jax.Array, lanes: jax.Array) -> jax.Array:
     """Per-row mixed seeds for a stream table: (N,) lanes -> (N,) seeds."""
     return mix_seed(jnp.asarray(seed, jnp.uint32),
                     jnp.asarray(lanes, jnp.uint32))
+
+
+# u * K1 mod 2^32 for each value u of a counter's low five bits.
+_K1_LOW = tuple((u * MURMUR_K1) & 0xFFFFFFFF for u in range(WORD_BITS))
+# Stage k of the bit permutation: the positions whose index has bit k clear.
+_SWAP_MASKS = (0x55555555, 0x33333333, 0x0F0F0F0F, 0x00FF00FF, 0x0000FFFF)
+
+
+def sng_words_jnp(row_seeds: jax.Array, thr: jax.Array, n_words: int,
+                  word_offset: jax.Array | None = None,
+                  total_words: int | None = None) -> jax.Array:
+    """The jnp SNG path: bit-identical to ``ref.sng_words_ref``, same args.
+
+    Bit ``t`` of a word is ``hash_u32((base + t) ^ seed) < thr``, and the
+    word's bit counter ``base`` is a multiple of 32, so ``(base + t) ^ seed``
+    is ``y ^ t`` with ``y = base ^ seed``.  As ``t < 2^16``, the finalizer's
+    first step gives ``c ^ t`` with ``c = y ^ (y >> 16)``; splitting ``c``
+    into ``hi = c & ~31`` and ``lo = c & 31``, its first multiply gives
+    ``hi * K1 + u * K1`` with ``u = lo ^ t``.  So the per-word part
+    (``hi * K1``) is computed once, and the 32 bits take ``u = 0..31`` with
+    a constant add in place of that multiply.  Bit ``u`` of the accumulator
+    belongs at position ``u ^ lo``: five masked swaps, one per bit of
+    ``lo``, move it there once per word.
+    """
+    b = thr.shape[-1]
+    total = jnp.uint32(n_words if total_words is None else total_words)
+    word_idx = jnp.arange(n_words, dtype=jnp.uint32)
+    if word_offset is not None:
+        word_idx = word_idx + jnp.asarray(word_offset, jnp.uint32)
+    base = ((jnp.arange(b, dtype=jnp.uint32)[:, None] * total
+             + word_idx[None, :])
+            * jnp.uint32(WORD_BITS))                         # (B, W) bit counters
+    y = base[None] ^ row_seeds.astype(jnp.uint32)[:, None, None]
+    c = y ^ (y >> jnp.uint32(16))                            # (N, B, W)
+    lo = c & jnp.uint32(WORD_BITS - 1)
+    hi_k1 = (c & jnp.uint32(0xFFFFFFE0)) * jnp.uint32(MURMUR_K1)  # (c & ~31) * K1
+    thr = thr[..., None]
+    acc = jnp.zeros(c.shape, jnp.uint32)
+    for u in range(WORD_BITS):
+        x = hi_k1 + jnp.uint32(_K1_LOW[u])
+        x = x ^ (x >> jnp.uint32(13))
+        x = x * jnp.uint32(MURMUR_K2)
+        x = x ^ (x >> jnp.uint32(16))
+        acc = acc | jnp.where(x < thr, jnp.uint32(1 << u), jnp.uint32(0))
+    for k, mask in enumerate(_SWAP_MASKS):
+        s, m = jnp.uint32(1 << k), jnp.uint32(mask)
+        swapped = ((acc & m) << s) | ((acc >> s) & m)
+        acc = jnp.where((lo & s) != 0, swapped, acc)
+    return acc
 
 
 def _kernel(seed_ref, thr_ref, o_ref, *, n_words: int, be: int, rb: int):
@@ -70,10 +119,12 @@ def sng_words(row_seeds: jax.Array, thr: jax.Array, n_words: int,
 
     ``row_seeds``: (N,) pre-mixed per-row seeds (``lane_seeds``); rows with
     equal seed share their uniforms (correlation groups decode exact |a-b|
-    under XOR).  ``thr``: (N, B) uint32 compare thresholds.  The jnp fallback
-    (``use_pallas=False``, the executor default) and the Pallas kernel are
-    bit-identical; ``interpret`` resolves through ``common.resolve_interpret``
-    (None: compiled on a TPU, elsewhere interpreted with a warning).
+    under XOR).  ``thr``: (N, B) uint32 compare thresholds.  The jnp path
+    (``sng_words_jnp``; ``use_pallas=False``, the executor default) and the
+    Pallas kernel are bit-identical, and both are tested against the oracle
+    ``ref.sng_words_ref``; ``interpret`` resolves through
+    ``common.resolve_interpret`` (None: compiled on a TPU, elsewhere
+    interpreted with a warning).
 
     The kernel's blocks meet the TPU's (8, 128) tiling rule at any table
     size: each grid step reads ``min(8, N)`` rows — their thresholds as a
@@ -88,9 +139,10 @@ def sng_words(row_seeds: jax.Array, thr: jax.Array, n_words: int,
 
     ``word_offset``/``total_words`` request a word *window* of a conceptual
     ``total_words``-long stream (see ``ref.sng_words_ref``) — exact because
-    the counter is the absolute bit index.  Windowed generation always runs
-    the jnp path: ``word_offset`` is typically a traced scan index, which the
-    grid-blocked Pallas kernel cannot take as a static.
+    the counter is the absolute bit index, which stays a multiple of 32 at
+    every word.  Windowed generation always runs the jnp path:
+    ``word_offset`` is typically a traced scan index, which the grid-blocked
+    Pallas kernel cannot take as a static.
     """
     total = n_words if total_words is None else total_words
     if thr.shape[-1] * total * WORD_BITS > 1 << 32:
@@ -104,8 +156,8 @@ def sng_words(row_seeds: jax.Array, thr: jax.Array, n_words: int,
             "the batch across keys or use key_mode='legacy'")
     windowed = word_offset is not None or total != n_words
     if not use_pallas or windowed:
-        return ref.sng_words_ref(row_seeds, thr, n_words,
-                                 word_offset=word_offset, total_words=total)
+        return sng_words_jnp(row_seeds, thr, n_words,
+                             word_offset=word_offset, total_words=total)
     n, b = thr.shape
     be = min(block_elems, b)
     rb = min(8, n)

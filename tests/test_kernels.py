@@ -11,11 +11,12 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ops, ref
-from repro.kernels.common import gen_packed_bits, hash_u32, threshold_u32
+from repro.kernels.common import (WORD_BITS, gen_packed_bits, hash_u32,
+                                  threshold_u32)
 from repro.kernels.packed_logic import packed_logic
 from repro.kernels.popcount_tree import popcount_hier
 from repro.kernels.sc_matmul import sc_matmul
-from repro.kernels.sng import lane_seeds, sng_pack, sng_words
+from repro.kernels.sng import lane_seeds, sng_pack, sng_words, sng_words_jnp
 
 KEY = jax.random.key(0)
 
@@ -111,6 +112,113 @@ def test_sng_words_shared_lane_shares_uniforms():
     seeds = lane_seeds(jnp.uint32(4), jnp.zeros((2,), jnp.uint32))
     w = sng_words(seeds, thr, 8)
     assert (w[0] & ~w[1]).sum() == 0
+
+
+# ------------------- jnp SNG path (per-word first round) vs oracle ----------------
+
+_THR_EDGES = np.asarray([0, 1, 1 << 31, 0xFFFFFFFF], np.uint32)
+_SEED_EDGES = np.asarray([0, 1, 0xFFFFFFFF], np.uint32)
+
+
+def _table(n, b, seed):
+    """(N,) row seeds and (N, B) thresholds: random, with the edge values
+    of both laid over the first rows and elements."""
+    rng = np.random.default_rng(seed)
+    thr = rng.integers(0, 1 << 32, (n, b), dtype=np.uint64).astype(np.uint32)
+    flat = thr.reshape(-1)
+    k = min(flat.size, _THR_EDGES.size)
+    flat[:k] = _THR_EDGES[:k]
+    seeds = rng.integers(0, 1 << 32, (n,), dtype=np.uint64).astype(np.uint32)
+    k = min(n, _SEED_EDGES.size)
+    seeds[:k] = _SEED_EDGES[:k]
+    return jnp.asarray(seeds), jnp.asarray(thr)
+
+
+@pytest.mark.parametrize("n,b,w", [
+    (1, 1, 1), (1, 100, 8), (1, 333, 32), (1, 1000, 4),
+    (7, 1, 4), (7, 100, 32), (7, 333, 1), (7, 1000, 8),
+    (13, 1, 8), (13, 100, 1), (13, 333, 4), (13, 1000, 32),
+])
+def test_sng_words_jnp_equals_ref(n, b, w):
+    seeds, thr = _table(n, b, n * 10_000 + b * 10 + w)
+    out = sng_words(seeds, thr, w)                 # the executor's default path
+    assert out.shape == (n, b, w)
+    assert (out == ref.sng_words_ref(seeds, thr, w)).all()
+
+
+@pytest.mark.parametrize("seed", [int(s) for s in _SEED_EDGES])
+@pytest.mark.parametrize("t", [int(t) for t in _THR_EDGES] + [0x9E3779B9])
+def test_sng_words_jnp_edge_seed_and_threshold(seed, t):
+    seeds = jnp.full((1,), seed, jnp.uint32)
+    thr = jnp.full((1, 64), t, jnp.uint32)
+    out = sng_words_jnp(seeds, thr, 8)
+    assert (out == ref.sng_words_ref(seeds, thr, 8)).all()
+    if t == 0:
+        assert (out == 0).all()
+
+
+@pytest.mark.parametrize("offset,n_win,total", [(0, 1, 8), (3, 4, 8),
+                                                (7, 1, 8), (5, 8, 32)])
+def test_sng_words_jnp_window_static_offset(offset, n_win, total):
+    seeds, thr = _table(7, 333, offset * 100 + n_win)
+    win = sng_words(seeds, thr, n_win, word_offset=offset, total_words=total)
+    whole = ref.sng_words_ref(seeds, thr, total)
+    assert (win == ref.sng_words_ref(seeds, thr, n_win, word_offset=offset,
+                                     total_words=total)).all()
+    assert (win == whole[..., offset:offset + n_win]).all()
+
+
+@pytest.mark.parametrize("n_win,total", [(1, 8), (2, 8), (4, 32)])
+def test_sng_words_jnp_window_traced_offset_in_scan(n_win, total):
+    # The chunked executor's pattern: word_offset is the scan's chunk index
+    # times the window, a traced value, and total_words > n_words.
+    seeds, thr = _table(13, 100, n_win * 1000 + total)
+
+    def chunk(carry, i):
+        off = i * jnp.uint32(n_win)
+        return carry, sng_words(seeds, thr, n_win, word_offset=off,
+                                total_words=total)
+
+    _, wins = jax.lax.scan(chunk, 0, jnp.arange(total // n_win, dtype=jnp.uint32))
+    got = jnp.concatenate(list(wins), axis=-1)
+    assert (got == ref.sng_words_ref(seeds, thr, total)).all()
+
+
+def test_sng_words_jnp_correlated_pair_shares_uniforms():
+    # Two rows with one seed (a correlation group): each equals the oracle,
+    # and the streams nest, so XOR decodes |a - b| exactly.
+    seed = lane_seeds(jnp.uint32(11), jnp.zeros((2,), jnp.uint32))
+    thr = jnp.stack([threshold_u32(jnp.full((333,), 0.25, jnp.float32)),
+                     threshold_u32(jnp.full((333,), 0.6, jnp.float32))])
+    out = sng_words_jnp(seed, thr, 8)
+    assert (out == ref.sng_words_ref(seed, thr, 8)).all()
+    assert (out[0] & ~out[1]).sum() == 0
+    assert (out[0] != out[1]).any()
+
+
+def _grid_muls(jaxpr, shape) -> int:
+    """`mul` equations over the (rows, elements, words) grid, sub-jaxprs
+    included: the multiplies done once per word or per bit.  The counter
+    base's element-index multiply is per element and is not counted."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "mul" and eqn.outvars[0].aval.shape == shape:
+            n += 1
+        for v in eqn.params.values():
+            sub = getattr(v, "jaxpr", v)
+            if hasattr(sub, "eqns"):
+                n += _grid_muls(sub, shape)
+    return n
+
+
+def test_sng_words_jnp_one_multiply_per_bit():
+    # The first finalizer multiply is per word; only the second is per bit.
+    seeds = jnp.zeros((3,), jnp.uint32)
+    thr = jnp.zeros((3, 5), jnp.uint32)
+    new = jax.make_jaxpr(lambda s, t: sng_words(s, t, 1, use_pallas=False))
+    old = jax.make_jaxpr(lambda s, t: ref.sng_words_ref(s, t, 1))
+    assert _grid_muls(new(seeds, thr).jaxpr, (3, 5, 1)) <= WORD_BITS + 1
+    assert _grid_muls(old(seeds, thr).jaxpr, (3, 5, 1)) == 2 * WORD_BITS
 
 
 # ----------------------------- packed logic --------------------------------------
