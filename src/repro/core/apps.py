@@ -395,7 +395,9 @@ def appnet_inputs(app: str, *, a=None, p=None, v=None, x_t=None,
 
     Shapes (trailing dims consumed, leading dims broadcast as batch):
       lit: ``a`` (..., 81) window pixels      ol: ``p`` (..., 16, 6) pixel probs
-      hdp: ``v`` dict over HDP_KEYS           kde: ``x_t`` (...), ``hist`` (..., N)
+      hdp: ``v`` dict over HDP_KEYS, or       kde: ``x_t`` (...), ``hist`` (..., N)
+           an array (..., 8) whose last
+           axis is in HDP_KEYS order
 
     Values stay *host* float32 (numpy): per-PI splats of an 81-pixel window
     would otherwise dispatch one device op per element, and host scalars are
@@ -414,7 +416,14 @@ def appnet_inputs(app: str, *, a=None, p=None, v=None, x_t=None,
         return {f"p{r}_{j}": p[..., r, j]
                 for r in range(p.shape[-2]) for j in range(p.shape[-1])}
     if app == "hdp":
-        return {k: _host(v[k]) for k in HDP_KEYS}
+        if isinstance(v, dict):
+            return {k: _host(v[k]) for k in HDP_KEYS}
+        v = _host(v)
+        if v.shape[-1:] != (len(HDP_KEYS),):
+            raise ValueError(f"hdp: v as an array must be (..., "
+                             f"{len(HDP_KEYS)}) in HDP_KEYS order, got "
+                             f"shape {v.shape}")
+        return {k: v[..., i] for i, k in enumerate(HDP_KEYS)}
     if app == "kde":
         hist = _host(hist)
         vals = {f"h{i}": hist[..., i] for i in range(hist.shape[-1])}

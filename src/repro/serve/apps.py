@@ -43,7 +43,8 @@ def app_request(app: str, key, bl: int = 256, *,
 
     ``inputs`` are the app-level keyword inputs of ``apps.appnet_inputs``
     (``lit``: ``a`` (..., 81); ``ol``: ``p`` (..., 16, 6); ``hdp``: ``v``
-    dict; ``kde``: ``x_t``, ``hist``).  ``key`` is the request's PRNG key —
+    dict over ``HDP_KEYS`` or array (..., 8) in that order; ``kde``:
+    ``x_t``, ``hist``).  ``key`` is the request's PRNG key —
     the served result is bit-identical to ``appnet_stochastic`` with the
     same key and netlist.
     """
