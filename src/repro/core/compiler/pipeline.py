@@ -30,7 +30,7 @@ import itertools
 from typing import Any, Callable
 
 from .. import obs
-from ..gates import Netlist
+from ..gates import Netlist, PrimaryInput
 from .ir import (FUSED_MUX, FUSED_XOR, BankPlan, CompiledOp, ExecutionPlan,
                  build_stream_table, member_prefix)
 from .stages import (_WGate, _WOp, _absorb_nots, _elide_and_cse, _find_mux_fusions,
@@ -393,3 +393,105 @@ def build_bank(members: "tuple[ExecutionPlan, ...]",
     return BankPlan(name=bank_name, members=members, comb=comb, seq=seq,
                     comb_members=comb_idx, seq_members=seq_idx,
                     serial=next_serial())
+
+
+# ------------------------------- state-cone split ---------------------------------
+
+@dataclasses.dataclass(frozen=True, eq=False)
+class ConeSplit:
+    """A sequential plan split at its state cone (``split_state_cone``).
+
+    ``cone`` holds every batched gate whose transitive inputs include a
+    state PI; ``hoisted`` (``None`` when no gate lies outside the cone)
+    holds every other gate, a combinational plan over the stream PIs whose
+    outputs are ``pre_outputs``.  ``boundary`` lists the nodes outside the
+    cone that the cone reads per bit: stream PIs and hoisted outputs read
+    by cone gates, and state drivers that are themselves hoisted.
+    ``serial_outputs`` are the plan outputs the cone computes;
+    ``taken_outputs`` pairs every other output with the node outside the
+    cone that holds its stream.  ``n_hoisted``/``n_serial`` count the
+    batched pass outputs on each side.
+    """
+
+    hoisted: ExecutionPlan | None
+    cone: ExecutionPlan
+    boundary: tuple[str, ...]
+    pre_outputs: tuple[str, ...]
+    serial_outputs: tuple[str, ...]
+    taken_outputs: tuple[tuple[str, str], ...]
+    n_hoisted: int
+    n_serial: int
+
+
+def split_state_cone(plan: ExecutionPlan) -> ConeSplit:
+    """Split a sequential plan into its state cone and the gates before it.
+
+    A gate outside the cone reads only stream PIs and other such gates.
+    Every op is bitwise, so running those gates once over whole packed
+    streams gives, at bit ``t`` of each word, the bit a per-bit run would
+    give at step ``t``: only the cone has to run bit by bit.  The split is
+    per batched gate, not per op, since a merged bank batches gates of both
+    sides into one op.  Each half re-enters the pipeline at the
+    ``liveness`` stage, so its ``slots``/``free_after`` are its own.
+    """
+    alias = dict(plan.aliases)
+    cone_nodes = set(plan.state_pis)
+    halves: tuple[list, list] = ([], [])          # hoisted, cone levels
+    for level in plan.levels:
+        ops: tuple[list, list] = ([], [])
+        for cop in level:
+            in_cone = [any(row[i] in cone_nodes for row in cop.inputs)
+                       for i in range(cop.n_batched)]
+            for side, side_ops in enumerate(ops):
+                idx = [i for i, c in enumerate(in_cone) if c == bool(side)]
+                if idx:
+                    side_ops.append(CompiledOp(
+                        op=cop.op, gids=tuple(cop.gids[i] for i in idx),
+                        inputs=tuple(tuple(row[i] for i in idx)
+                                     for row in cop.inputs),
+                        outputs=tuple(cop.outputs[i] for i in idx),
+                        neg=cop.neg))
+            cone_nodes.update(o for o, c in zip(cop.outputs, in_cone) if c)
+        for levels, side_ops in zip(halves, ops):
+            if side_ops:
+                levels.append(tuple(side_ops))
+    hoisted_levels, cone_levels = halves
+
+    def outside(names) -> tuple[str, ...]:
+        return tuple(dict.fromkeys(n for n in names if n not in cone_nodes))
+
+    serial = tuple(o for o in plan.outputs if alias.get(o, o) in cone_nodes)
+    taken = tuple((o, alias.get(o, o)) for o in plan.outputs
+                  if alias.get(o, o) not in cone_nodes)
+    boundary = outside([n for level in cone_levels for cop in level
+                        for row in cop.inputs for n in row]
+                       + [alias.get(d, d) for d in plan.state_drivers])
+    pre_outputs = outside([*boundary, *(src for _, src in taken)])
+
+    def n_outputs(levels) -> int:
+        return sum(cop.n_batched for level in levels for cop in level)
+
+    hoisted = None
+    if hoisted_levels:
+        hoisted = DEFAULT_PIPELINE.run(Lowering(
+            name=f"{plan.name}/hoisted",
+            pis=tuple(p for p in plan.pis if p.name not in plan.state_pis),
+            outputs=pre_outputs, fuse=plan.fused,
+            n_gates=n_outputs(hoisted_levels),
+            levels=tuple(hoisted_levels)), start="liveness")
+    observable = {*serial, *plan.state_drivers}
+    cone_ctx = Lowering(
+        name=f"{plan.name}/cone",
+        pis=(*(p for p in plan.pis if p.name in plan.state_pis),
+             *(PrimaryInput(b) for b in boundary)),
+        outputs=serial, state_pis=plan.state_pis,
+        state_drivers=plan.state_drivers, state_inits=plan.state_inits,
+        fuse=plan.fused, n_gates=n_outputs(cone_levels),
+        levels=tuple(cone_levels))
+    cone_ctx.alias = {s: d for s, d in plan.aliases if s in observable}
+    return ConeSplit(
+        hoisted=hoisted,
+        cone=DEFAULT_PIPELINE.run(cone_ctx, start="liveness"),
+        boundary=boundary, pre_outputs=pre_outputs, serial_outputs=serial,
+        taken_outputs=taken, n_hoisted=n_outputs(hoisted_levels),
+        n_serial=n_outputs(cone_levels))

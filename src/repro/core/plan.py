@@ -27,14 +27,15 @@ pattern — hit both the plan cache and the downstream jit cache.
 """
 from __future__ import annotations
 
+import weakref
 from collections import OrderedDict, defaultdict
 
 from .compiler.ir import (_COMMUTATIVE, _OP_ARITY, FUSED_MUX, FUSED_XOR,  # noqa: F401
                           IDENTITY_NAME, BankPlan, CompiledOp, ExecutionPlan,
                           StreamTable, build_stream_table, member_prefix)
-from .compiler.pipeline import (DEFAULT_PIPELINE, PassPipeline,  # noqa: F401
-                                build_bank, lower_netlist, merge_plans,
-                                next_serial)
+from .compiler.pipeline import (DEFAULT_PIPELINE, ConeSplit,  # noqa: F401
+                                PassPipeline, build_bank, lower_netlist,
+                                merge_plans, next_serial, split_state_cone)
 from .compiler.stages import signature as _signature  # noqa: F401
 from .gates import Netlist
 
@@ -381,3 +382,20 @@ def merged_pass_count(plans: "list[ExecutionPlan]") -> int:
                     by_level[lvl].add((cop.op, cop.neg))
         total += sum(len(s) for s in by_level.values())
     return total
+
+
+#: Plan -> its state-cone split; weak, so a split lives as long as its plan.
+_CONE_SPLITS: "weakref.WeakKeyDictionary[ExecutionPlan, ConeSplit]" = \
+    weakref.WeakKeyDictionary()
+
+
+def state_cone_split(plan: ExecutionPlan) -> ConeSplit:
+    """``split_state_cone(plan)``, once per plan object.
+
+    The halves are plans themselves and key jit caches downstream (the
+    megakernel's), so one plan must always yield the same two.
+    """
+    split = _CONE_SPLITS.get(plan)
+    if split is None:
+        split = _CONE_SPLITS[plan] = split_state_cone(plan)
+    return split

@@ -13,11 +13,13 @@ pass:
     passes through ``packed_logic.py``'s VMEM-tiled kernel, including the
     fused 4-gate MUX path.
 
-Sequential (stateful) netlists — the Gaines-divider class — run as a
-``lax.scan`` over *words* with an inner 32-step bit loop, so the feedback
-wavefront never materializes the eager time-major (BL, ...) bit tensor the
-interpreter builds (32x less live memory at BL=1024, and the whole recurrence
-stays inside one jit).
+Sequential (stateful) netlists — the Gaines-divider class — are split at
+their state cone.  The gates that read no state run once over the whole
+packed streams, like a combinational plan; only the cone, the gates whose
+inputs reach back to a flip-flop, runs as a ``lax.scan`` over *words* with
+an inner 32-step bit loop.  The feedback wavefront never materializes the
+eager time-major (BL, ...) bit tensor the interpreter builds (32x less live
+memory at BL=1024, and the whole recurrence stays inside one jit).
 
 Everything here is bit-identical to the gate-by-gate interpreter: fused ops
 are boolean identities and per-gate fault injection uses the same per-gate
@@ -30,7 +32,8 @@ import jax.numpy as jnp
 
 from ..core import bitstream as bs
 from ..core import faults as _faults
-from ..core.plan import FUSED_MUX, ExecutionPlan
+from ..core import obs
+from ..core.plan import FUSED_MUX, ExecutionPlan, state_cone_split
 from .packed_logic import packed_logic
 
 # Plan op -> packed_logic op name (ops the Pallas kernel implements).
@@ -175,7 +178,7 @@ def run_sequential(plan: ExecutionPlan, pi_words: dict[str, jax.Array],
                    batch_shape: tuple[int, ...] | None = None,
                    megakernel: bool = False,
                    interpret: bool | None = None) -> dict[str, jax.Array]:
-    """Run a stateful plan as scan-over-words with an inner 32-bit loop.
+    """Run a stateful plan: its state cone bit by bit, the rest once.
 
     ``pi_words``: packed streams for every non-state PI, shape (..., W).
     Returns packed output streams of the same shape.  State cells are carried
@@ -183,66 +186,97 @@ def run_sequential(plan: ExecutionPlan, pi_words: dict[str, jax.Array],
     output is the circuit's emission at time step ``t``, with state read
     *before* update — exactly the interpreter's scan semantics.
 
+    The plan is split at its state cone (``core.plan.state_cone_split``).
+    The gates outside it read no state, so they run first as one
+    ``run_combinational`` over the whole packed streams.  Only the cone runs
+    inside the scan over words and its 32-step bit loop, which takes each
+    boundary node (a stream PI or hoisted node the cone reads) as its own
+    (W, ...) array and extracts bit ``t`` of its word.  An output outside
+    the cone comes from the first run whole.  Exact: every op is bitwise,
+    so bit ``t`` of a hoisted word is the bit a per-bit run gives at step
+    ``t``.  Counters ``scan.hoisted_outputs``/``scan.serial_outputs`` of
+    ``obs.REGISTRY`` add the batched pass outputs on each side, once per
+    trace.
+
     Members of a bank-merged sequential plan may carry different (broadcast-
-    compatible) batch shapes; the scan then runs at the common shape and the
+    compatible) batch shapes; the hoisted gates run at native shapes and
+    their outputs broadcast to the common shape the scan runs at, and the
     caller restricts each member's outputs back to its native shape (exact:
     every op is elementwise, so restriction commutes with the recurrence).
     Plans with zero stream PIs (state-only recurrences, e.g. a NOT-feedback
-    oscillator) have nothing to stack — ``n_words`` then supplies the scan
-    length that is otherwise read off the stacked words, and ``batch_shape``
-    the batch shape that is otherwise read off the stacked words' leading
-    dims (without it a batched request would silently collapse to scalar
-    state and outputs).
+    oscillator) have nothing to hoist — ``n_words`` then supplies the scan
+    length that is otherwise read off the streams, and ``batch_shape`` the
+    batch shape that is otherwise read off their leading dims (without it a
+    batched request would silently collapse to scalar state and outputs).
 
-    ``megakernel``/``interpret`` forward to the per-bit combinational body.
-    Its operations carry the name scope ``sc.scan`` in the device trace.
+    ``use_pallas``/``megakernel``/``interpret`` forward to both halves.  All
+    of it carries the name scope ``sc.scan`` in the device trace; the
+    hoisted run's own ``sc.passes`` scope nests under it.
     """
     with jax.named_scope("sc.scan"):
+        split = state_cone_split(plan)
+        obs.REGISTRY.inc("scan.hoisted_outputs", split.n_hoisted)
+        obs.REGISTRY.inc("scan.serial_outputs", split.n_serial)
         names = plan.stream_pi_names()
         if names:
-            shapes = {pi_words[n].shape for n in names}
-            if len(shapes) > 1:
-                common = jnp.broadcast_shapes(*shapes)
-                stacked = jnp.stack([jnp.broadcast_to(pi_words[n], common)
-                                     for n in names])              # (P, ..., W)
-            else:
-                stacked = jnp.stack([pi_words[n] for n in names])  # (P, ..., W)
-            batch = stacked.shape[1:-1]
-            xs = jnp.moveaxis(stacked, -1, 0)                      # (W, P, ...)
+            common = jnp.broadcast_shapes(*(pi_words[n].shape for n in names))
+        elif n_words is None:
+            raise ValueError(
+                f"plan {plan.name} has no stream PIs; pass n_words "
+                "(= bitstream_length // 32) to size the scan")
         else:
-            if n_words is None:
-                raise ValueError(
-                    f"plan {plan.name} has no stream PIs; pass n_words "
-                    "(= bitstream_length // 32) to size the scan")
-            batch = tuple(batch_shape) if batch_shape else ()
-            xs = jnp.zeros((n_words, 0), jnp.uint32)               # (W, 0)
+            common = (*(batch_shape or ()), n_words)
+        batch, w = tuple(common[:-1]), common[-1]
 
-        state0 = tuple(jnp.full(batch, jnp.uint32(round(init)))
-                       for init in plan.state_inits)
-        n_out = len(plan.outputs)
+        env = {n: pi_words[n] for n in names}
+        if split.hoisted is not None:
+            run_combinational(split.hoisted, env, use_pallas=use_pallas,
+                              megakernel=megakernel, interpret=interpret)
+        words = {n: jnp.broadcast_to(env[n], common)
+                 for n in split.pre_outputs}
+        outs = {o: words[src] for o, src in split.taken_outputs}
+        if split.serial_outputs:
+            xs = tuple(jnp.moveaxis(words[b], -1, 0)           # each (W, ...)
+                       for b in split.boundary)
+            ys = _bit_loop(split, xs, batch, w, use_pallas, megakernel,
+                           interpret)
+            outs.update((o, jnp.moveaxis(y, 0, -1))
+                        for o, y in zip(split.serial_outputs, ys))
+        return {o: outs[o] for o in plan.outputs}
 
-        def word_step(state, word):                                # word: (P, ...)
-            zeros = tuple(jnp.zeros(batch, jnp.uint32) for _ in range(n_out))
 
-            def bit_step(i, carry):
-                state, out_words = carry
-                sh = jnp.uint32(i)
-                env = {n: (word[j] >> sh) & jnp.uint32(1)
-                       for j, n in enumerate(names)}
-                for s_name, s_val in zip(plan.state_pis, state):
-                    env[s_name] = s_val
-                run_combinational(plan, env, use_pallas=use_pallas,
-                                  megakernel=megakernel, interpret=interpret)
-                new_state = tuple(env[d] for d in plan.state_drivers)
-                # Mask to bit 0 before packing: inverting gates (~x) carry
-                # garbage in bits 1..31 of the per-bit env values.
-                out_words = tuple(w | ((env[o] & jnp.uint32(1)) << sh)
-                                  for w, o in zip(out_words, plan.outputs))
-                return new_state, out_words
+def _bit_loop(split, xs: tuple[jax.Array, ...], batch: tuple[int, ...],
+              n_words: int, use_pallas: bool, megakernel: bool,
+              interpret: bool | None) -> tuple[jax.Array, ...]:
+    """The state cone as a scan over words with an inner 32-step bit loop.
 
-            state, out_words = jax.lax.fori_loop(0, bs.WORD_BITS, bit_step,
-                                                 (state, zeros))
-            return state, out_words
+    ``xs[k]`` holds ``split.boundary[k]``'s words, (W, ...).  Returns each
+    serial output's packed words, (W, ...).
+    """
+    cone = split.cone
+    state0 = tuple(jnp.full(batch, jnp.uint32(round(init)))
+                   for init in cone.state_inits)
 
-        _, ys = jax.lax.scan(word_step, state0, xs)                # each: (W, ...)
-        return {o: jnp.moveaxis(y, 0, -1) for o, y in zip(plan.outputs, ys)}
+    def word_step(state, word):                    # word: one (...) per node
+        zeros = tuple(jnp.zeros(batch, jnp.uint32)
+                      for _ in split.serial_outputs)
+
+        def bit_step(i, carry):
+            state, out_words = carry
+            sh = jnp.uint32(i)
+            env = {n: (x >> sh) & jnp.uint32(1)
+                   for n, x in zip(split.boundary, word)}
+            env.update(zip(cone.state_pis, state))
+            run_combinational(cone, env, use_pallas=use_pallas,
+                              megakernel=megakernel, interpret=interpret)
+            new_state = tuple(env[d] for d in cone.state_drivers)
+            # Mask to bit 0 before packing: inverting gates (~x) carry
+            # garbage in bits 1..31 of the per-bit env values.
+            out_words = tuple(w | ((env[o] & jnp.uint32(1)) << sh)
+                              for w, o in zip(out_words, split.serial_outputs))
+            return new_state, out_words
+
+        return jax.lax.fori_loop(0, bs.WORD_BITS, bit_step, (state, zeros))
+
+    _, ys = jax.lax.scan(word_step, state0, xs, length=n_words)
+    return ys

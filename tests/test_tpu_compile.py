@@ -111,3 +111,19 @@ def test_pallas_app_program_keeps_layouts(one_chip, app, inputs):
         net, v, k, opts)), values, key).as_text()
     assert "sng_words" in text and "packed_logic" in text
     assert not re.search(r"= \S+ (transpose|copy)\(", text)
+
+
+def test_hdp_batch_program_compiles_within_its_temp(one_chip):
+    # The benchmark's HDP batch: 4,194,304 queries at BL=256.  The gates
+    # before the JK divider run once over whole streams, so the 11-row
+    # stream table dies before the scan: temp stays at most the
+    # 2,684,580,352 B the program planned when every bit step re-read it.
+    values = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), jnp.float32,
+                                       sharding=one_chip),
+        apps.appnet_inputs("hdp", v=np.zeros((4194304, 8), np.float32)))
+    key = jax.ShapeDtypeStruct((), jax.random.key(0).dtype, sharding=one_chip)
+    opts = executor.ExecOptions(bitstream_length=256, decode=True)
+    c = _compile(lambda v, k: executor.run(executor.ExecRequest(
+        APP_NETLISTS["hdp"](), v, k, opts)), values, key)
+    assert c.memory_analysis().temp_size_in_bytes <= 2_684_580_352
