@@ -1,10 +1,7 @@
 """Serving subsystems.
 
 ``sc_engine`` / ``apps`` — the dynamic SC bank server (request admission,
-bucketed padded BankPlans, per-request key threading).  The LM serving entry
-points (``make_prefill`` / ``make_decode_step`` / ``greedy_generate``) load
-lazily: they pull in the whole ``repro.models`` stack, which the SC serving
-path does not need.
+bucketed padded BankPlans, per-request key threading).
 """
 from ..core.executor import ExecOptions, ExecRequest
 from ..core.faults import FaultModel
@@ -17,14 +14,4 @@ __all__ = [
     "ExecRequest", "FaultModel", "RequestShed", "SCRequest", "ServerClosed",
     "Ticket",
     "app_netlist", "app_request", "circuit_request",
-    "make_decode_step", "make_prefill", "greedy_generate",
 ]
-
-_LM_EXPORTS = ("make_decode_step", "make_prefill", "greedy_generate")
-
-
-def __getattr__(name):
-    if name in _LM_EXPORTS:
-        from . import engine
-        return getattr(engine, name)
-    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

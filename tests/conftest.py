@@ -1,7 +1,7 @@
 """Shared test config.
 
-NOTE: tests must see the single real CPU device — the 512-device
-XLA_FLAGS override belongs to launch/dryrun.py ONLY.
+NOTE: tests must see the CPU devices the process was started with; no test
+sets ``XLA_FLAGS`` in-process (CI forces 4 host devices from outside).
 
 Property tests use ``hypothesis`` directly, under the fast profile below.
 
